@@ -53,4 +53,6 @@ def run(n_train=2000, n_test=500, clients=8, rounds=8, seed=0):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

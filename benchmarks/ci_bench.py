@@ -255,14 +255,20 @@ def bench_mesh(clients):
     (the forced-device-count XLA flag must precede the jax import, and
     this process imported jax long ago). Subprocess RSS does not count
     toward this process's ru_maxrss, so running it after the RSS sample
-    changes nothing — but the fused sections stay adjacent on purpose."""
+    changes nothing — but the fused sections stay adjacent on purpose.
+
+    The child is pinned to the CPU (JAX_PLATFORMS=cpu): it is a
+    forced-host-device check, and on a machine with an accelerator this
+    process already holds the chip, so a child that reached for it
+    would fail or hang."""
     import subprocess
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out = subprocess.run(
         [sys.executable, "-m", "benchmarks.mesh_bench", "--devices", "8",
          "--clients", str(clients), "--rounds", "4"],
         capture_output=True, text=True, timeout=900, cwd=repo,
-        env=dict(os.environ, PYTHONPATH=os.path.join(repo, "src")))
+        env=dict(os.environ, PYTHONPATH=os.path.join(repo, "src"),
+                 JAX_PLATFORMS="cpu"))
     if out.returncode != 0:
         raise RuntimeError(f"mesh_bench failed: {out.stderr[-2000:]}")
     return json.loads(out.stdout.strip().splitlines()[-1])
@@ -609,4 +615,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

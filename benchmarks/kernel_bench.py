@@ -1,9 +1,10 @@
 """Kernel micro-benchmarks.
 
-On this CPU container the Pallas kernels execute in interpret mode (not
+On the CPU the Pallas kernels execute in interpret mode (not
 representative of TPU), so wall-clock timings are taken on the jnp
-REFERENCE paths (the computation the kernels implement) and the derived
-column reports the analytic TPU-roofline time for the same op — the
+REFERENCE paths (the computation the kernels implement; XLA:CPU
+timings) and the derived column reports the analytic roofline time of
+the same op on a TPU v5e (peaks from `benchmarks/peaks.json`) — the
 number the BlockSpec tiling is designed against.
 
 CSV: name,us_per_call,derived
@@ -13,8 +14,12 @@ import time
 import jax
 import jax.numpy as jnp
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
+from repro.launch.roofline import V5E, device_peaks
+
+# the derived tpu_roofline_us column is the TPU v5e target, whatever
+# device the timings ran on
+PEAK_FLOPS = device_peaks(V5E)["bf16_flops"]
+HBM_BW = device_peaks(V5E)["hbm_bytes_per_s"]
 
 
 def _time(fn, *args, iters=5):
@@ -561,4 +566,7 @@ if __name__ == "__main__":
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", default="quick", choices=sorted(ENGINE_SWEEPS))
-    main(ap.parse_args().scale)
+    args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    main(args.scale)

@@ -73,6 +73,8 @@ def main(argv=None):
         raise RuntimeError(
             "benchmarks.mesh_bench must run in its own process: jax was "
             "imported before the forced-device-count flag could be set")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     doc = measure(args.devices, args.clients, args.rounds,
                   strategy=args.strategy, chunk=args.chunk)

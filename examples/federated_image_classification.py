@@ -43,9 +43,11 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro import api
 from repro.data.synthetic import DATASETS
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--strategy", choices=api.strategy_names(),
                     default="cfl",

@@ -1,7 +1,8 @@
 """Multi-pod dry-run demo: lower + compile one (arch x shape) on the
 single-pod (16x16=256) and multi-pod (2x16x16=512) production meshes and
 print the roofline terms. Runs in a subprocess so the 512 fake host
-devices never leak into the parent.
+devices never leak into the parent; this parent never imports JAX, and
+the dry-run pins itself to the CPU, so no chip is taken.
 
     PYTHONPATH=src python examples/multipod_dryrun_demo.py --arch yi-9b
 """
@@ -23,7 +24,7 @@ def main():
 
     cmd = [sys.executable, "-m", "repro.launch.dryrun", "--mesh", "both",
            "--arch", args.arch, "--force", "--out",
-           "/tmp/repro_dryrun_demo"]
+           os.path.join("experiments", "dryrun", "demo")]
     if args.fl:
         cmd += ["--fl", args.fl]
     else:

@@ -539,8 +539,7 @@ def hfl_tier1_local(stacked: Params, weights, num_groups_local: int, *,
 
 
 def mesh_hfl_stacked(stacked: Params, weights, num_groups: int, *,
-                     axis: str = "data",
-                     force_fallback: bool = False) -> Params:
+                     axis: str = "data") -> Params:
     """Two-tier HFL over a SHARDED client stack: the general operator
     behind the `mesh_hfl` parity suite, supporting group sizes above,
     equal to, and below the shard size (the fused executor's own path
@@ -550,16 +549,14 @@ def mesh_hfl_stacked(stacked: Params, weights, num_groups: int, *,
     * group size <= shard size (groups nest in shards): tier 1 is the
       local reshape (`hfl_tier1_local`), tier 2 one weighted psum.
     * group size > shard size (groups span whole shards): tier 1 is a
-      grouped psum over `axis_index_groups` — or, where the backend
-      rejects that (0.4.x shard_map) or `force_fallback` is set, the
-      PR 1 one-hot-masked full psum with identical math. Tier 2 then
-      exploits the tier-1 replication within each group: the gw-weighted
-      full-axis psum overcounts numerator AND denominator by exactly the
-      group's shard count, which cancels (same argument as `mesh_hfl`).
+      grouped psum over `axis_index_groups`. Tier 2 then exploits the
+      tier-1 replication within each group: the gw-weighted full-axis
+      psum overcounts numerator AND denominator by exactly the group's
+      shard count, which cancels (same argument as `mesh_hfl`).
 
     Matches host `hfl_aggregate` on the gathered stack
     (tests/test_fl_mesh_dryrun.py)."""
-    ndev = _axis_size(axis)
+    ndev = jax.lax.axis_size(axis)
     w = jnp.asarray(weights, jnp.float32)
     C_loc = w.shape[0]
     C = C_loc * ndev
@@ -574,39 +571,17 @@ def mesh_hfl_stacked(stacked: Params, weights, num_groups: int, *,
         raise ValueError(
             f"group size {per} neither nests in nor spans whole shards "
             f"of {C_loc} clients")
-    m = per // C_loc                      # shards per group
     dev_groups = topology.mesh_axis_groups(ndev, num_groups)
-    part_w = jnp.sum(w)
     part = jax.tree.map(
         lambda p: jnp.sum(p.astype(jnp.float32) * _bcast(w, p), axis=0),
         stacked)
-
-    def grouped_psum(x):
-        if force_fallback:
-            raise NotImplementedError
-        return jax.lax.psum(x, axis, axis_index_groups=dev_groups)
-
-    try:
-        gw = grouped_psum(part_w)
-        group = jax.tree.map(lambda p: grouped_psum(p) / gw, part)
-    except NotImplementedError:
-        # one-hot-masked full psum (PR 1 fallback): every shard
-        # contributes its partial into its group's slot of a (G, ...)
-        # expansion, ONE full-axis psum yields all group sums, each
-        # shard reads back its own group's row
-        idx = jax.lax.axis_index(axis)
-        onehot = (jnp.arange(num_groups) == idx // m).astype(jnp.float32)
-        gw = jnp.tensordot(onehot,
-                           jax.lax.psum(onehot * part_w, axis), axes=1)
-
-        def tier1(p):
-            e = onehot.reshape((num_groups,) + (1,) * p.ndim) * p
-            return jnp.tensordot(onehot, jax.lax.psum(e, axis),
-                                 axes=1) / gw
-
-        group = jax.tree.map(tier1, part)
-    # tier 2: each group model is replicated across its m member shards,
-    # so numerator and denominator both overcount by m — cancels
+    gw = jax.lax.psum(jnp.sum(w), axis, axis_index_groups=dev_groups)
+    group = jax.tree.map(
+        lambda p: jax.lax.psum(p, axis, axis_index_groups=dev_groups) / gw,
+        part)
+    # tier 2: each group model is replicated across its per // C_loc
+    # member shards, so numerator and denominator both overcount by that
+    # count — it cancels
     return jax.tree.map(
         lambda p: ((jax.lax.psum(p * gw, axis)
                     / jax.lax.psum(gw, axis)).astype(jnp.float32)),
@@ -643,14 +618,6 @@ def mesh_gossip_stacked(stacked: Params, mix, *, axis: str = "data"
 # mesh-level (inside shard_map) operators — pod-scale FL
 # ===========================================================================
 
-def _axis_size(name: str) -> int:
-    """Static mesh-axis size inside shard_map — `jax.lax.axis_size` on new
-    jax, `jax.core.axis_frame` (which returns the size) on 0.4.x."""
-    if hasattr(jax.lax, "axis_size"):
-        return int(jax.lax.axis_size(name))
-    return int(jax.core.axis_frame(name))
-
-
 def _wavg_psum(params, weight, axes):
     """Weighted mean over mesh axes: psum(w*theta)/psum(w)."""
     total_w = jax.lax.psum(weight, axes)
@@ -661,19 +628,13 @@ def _wavg_psum(params, weight, axes):
 
 
 def mesh_hfl(params, weight, *, client_axis="data",
-             num_groups: int = 2, pod_axis: Optional[str] = None,
-             force_fallback: bool = False):
+             num_groups: int = 2, pod_axis: Optional[str] = None):
     """Two-tier hierarchical aggregation.
 
     Single-pod: tier 1 over `axis_index_groups` partitions of the client
     axis, tier 2 over the full client axis. Multi-pod: tier 1 over the
     intra-pod client axis, tier 2 over the pod axis — the exact
     clients -> group-server -> global-server schedule of paper Fig. 1.
-
-    `force_fallback` routes tier 1 through the one-hot-masked full psum
-    even where the backend supports `axis_index_groups` — so the parity
-    suite pins BOTH implementations against the host aggregate rather
-    than whichever one the installed jax happens to pick.
     """
     if pod_axis is not None:
         group = _wavg_psum(params, weight, client_axis)          # tier 1
@@ -683,37 +644,15 @@ def mesh_hfl(params, weight, *, client_axis="data",
                        / jax.lax.psum(gw, pod_axis)).astype(p.dtype),
             group)
 
-    axis_size = _axis_size(client_axis)
-    groups = topology.mesh_axis_groups(axis_size, num_groups)
-    # tier 1: group-server aggregate — partial collectives over the
-    # axis_index_groups partition where the backend supports them, else a
-    # one-hot-masked full psum: every device contributes its weighted
-    # params into its group's slot of a (G, ...) expansion, the full-axis
-    # psum produces all G group sums at once, and each device reads back
-    # its own group's row (identical math, 0.4.x-shard_map portable).
-    try:
-        if force_fallback:
-            raise NotImplementedError
-        gw = jax.lax.psum(weight, client_axis, axis_index_groups=groups)
-        group = jax.tree.map(
-            lambda p: (jax.lax.psum(p.astype(jnp.float32) * weight,
-                                    client_axis, axis_index_groups=groups)
-                       / gw).astype(p.dtype),
-            params)
-    except NotImplementedError:
-        per = axis_size // num_groups
-        idx = jax.lax.axis_index(client_axis)
-        onehot = (jnp.arange(num_groups) == idx // per).astype(jnp.float32)
-        gw = jnp.tensordot(onehot,
-                           jax.lax.psum(onehot * weight, client_axis), axes=1)
-
-        def tier1(p):
-            e = (onehot.reshape((num_groups,) + (1,) * p.ndim)
-                 * (p.astype(jnp.float32) * weight))
-            return (jnp.tensordot(onehot, jax.lax.psum(e, client_axis),
-                                  axes=1) / gw).astype(p.dtype)
-
-        group = jax.tree.map(tier1, params)
+    groups = topology.mesh_axis_groups(jax.lax.axis_size(client_axis),
+                                       num_groups)
+    # tier 1: group-server aggregate — a psum over each partition
+    gw = jax.lax.psum(weight, client_axis, axis_index_groups=groups)
+    group = jax.tree.map(
+        lambda p: (jax.lax.psum(p.astype(jnp.float32) * weight,
+                                client_axis, axis_index_groups=groups)
+                   / gw).astype(p.dtype),
+        params)
     # tier 2: global-server aggregate over group models. Each group model
     # is replicated across its (equal-size) group, so the gw-weighted sum
     # over the full axis overcounts numerator AND denominator by exactly
@@ -740,7 +679,7 @@ def mesh_afl_gossip(params, *, client_axis="data", steps: int = 1):
     """Ring gossip: each client averages with its +-1 ring neighbors via
     collective_permute — O(2 * |params|) link traffic per step, no global
     collective. Iterating converges to the consensus mean."""
-    n = _axis_size(client_axis)
+    n = jax.lax.axis_size(client_axis)
     fwd = [(i, (i + 1) % n) for i in range(n)]
     bwd = [(i, (i - 1) % n) for i in range(n)]
 

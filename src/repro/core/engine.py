@@ -167,9 +167,11 @@ def _train_clients_chunked_impl(stacked_params, data, *, stacked_loss_fn,
     to (C//chunk, chunk, ...) and a `lax.map` trains one chunk per step,
     so peak training-activation memory scales with `chunk` rather than
     the federation size — what lifts the fused client sweep past the
-    single-stack ceiling. Results are bitwise the chunk-order
-    concatenation of independent per-chunk runs, and clients are
-    independent, so this equals the unchunked path."""
+    single-stack ceiling. Clients are independent, so this computes the
+    same math as the unchunked path; inside the fused round it is a
+    different XLA program, whose float reductions XLA may fuse and order
+    differently, so the two agree to float rounding, not bitwise
+    (tests/test_fused.py)."""
     C = jax.tree.leaves(stacked_params)[0].shape[0]
     if chunk <= 0 or chunk >= C:
         return _train_clients_impl(

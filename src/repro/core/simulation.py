@@ -1002,6 +1002,9 @@ class FederatedSimulation:
             tel.counter("codec.uplink_bytes",
                         sum(len(p) * bw for p in pids_l))
         state = strat.scan_uncarry(self, carry)
+        # kept for inspection: the compiled scan (HLO text, shardings,
+        # memory analysis) and the final strategy state
+        self.fused_program, self.final_state = compiled, state
         acc_r, loss_r, tacc_r = (np.asarray(acc_r), np.asarray(loss_r),
                                  np.asarray(tacc_r))
         curves = {"train_acc": [], "train_loss": [], "test_acc": []}
@@ -1120,9 +1123,12 @@ class FederatedSimulation:
                 specs, tree,
                 is_leaf=lambda x: isinstance(x, P))
 
-        wrapped = mesh_launch.shard_map_compat(
-            run, mesh, in_specs=(carry_specs, xs_specs, consts_specs),
-            out_specs=out_specs)
+        # replication checking is off: local client ids come from
+        # `axis_index` arithmetic, which check_vma cannot type through
+        # `lax.scan` (the §11 parity tests pin correctness instead)
+        wrapped = jax.shard_map(
+            run, mesh=mesh, in_specs=(carry_specs, xs_specs, consts_specs),
+            out_specs=out_specs, check_vma=False)
         return (wrapped, _put(carry0, carry_specs), _put(xs, xs_specs),
                 _put(consts, consts_specs))
 

@@ -11,9 +11,12 @@ NEG_INF = -2.0e38
 
 
 def fedavg_agg_ref(stacked, weights):
-    """stacked: (C, N) client-stacked flat params; weights: (C,) sum=1."""
+    """stacked: (C, N) client-stacked flat params; weights: (C,) sum=1.
+    Full f32 precision (a TPU's default f32 matmul multiplies in bf16)."""
     return jnp.einsum("c,cn->n", weights.astype(jnp.float32),
-                      stacked.astype(jnp.float32)).astype(stacked.dtype)
+                      stacked.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST
+                      ).astype(stacked.dtype)
 
 
 def trimmed_mean_ref(stacked, trim: int):

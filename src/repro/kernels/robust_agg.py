@@ -42,11 +42,12 @@ is exposed as `trimmed_mean_jnp` — the production CPU path
 driven and ~8x slower than the vectorized network at C=64, which is
 what held the robust/fedavg latency ratio at ~95x.
 
-Tiling: 1-D blocks of the flattened parameter vector, like `fedavg_agg`.
-Each grid step loads a (C, BLOCK) tile into VMEM; the network runs
-in-register/VMEM on the VPU (~log^2 C fp32 copies of the tile live at
-once, so the default block is scaled down with C to keep the working
-set inside VMEM).
+Tiling: lane blocks of the flattened parameter vector, like `fedavg_agg`.
+Each grid step loads a (C, BLOCK) tile into VMEM and writes a (1, BLOCK)
+tile; the network runs in VMEM on the VPU on the power-of-two padded
+(Cp, BLOCK) f32 tile. BLOCK comes from the shared VMEM budget
+(`kernels/tiling.py`), counting `_NETWORK_COPIES` live copies of that
+padded tile.
 """
 from __future__ import annotations
 
@@ -56,9 +57,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import tiling
+
 
 DEFAULT_BLOCK = 8192
-_TILE_BUDGET = 512 * 1024          # floats per (C, BLOCK) tile
+# (Cp, BLOCK) f32 copies the network may keep live at once, counted as
+# an upper bound: the padded tile, one fused `_merge4` pass's input,
+# its min/max intermediates and its stacked output. Mosaic holds less
+# (the v5e compile accepts blocks 4x wider at C = 64 and 1024).
+_NETWORK_COPIES = 6
 
 
 def _pow2_pad_rows(x, value):
@@ -157,9 +164,22 @@ def _select_window(sorted_x, lo: int, hi: int, out_dtype):
 
 
 def _trimmed_kernel(x_ref, o_ref, *, lo: int, hi: int):
-    # x_ref: (C, BLOCK) VMEM tile; o_ref: (BLOCK,)
+    # x_ref: (C, BLOCK) VMEM tile; o_ref: (1, BLOCK)
     x = x_ref[...].astype(jnp.float32)
-    o_ref[...] = _select_window(bitonic_sorted(x), lo, hi, o_ref.dtype)
+    o_ref[...] = _select_window(bitonic_sorted(x), lo, hi,
+                                o_ref.dtype)[None]
+
+
+def _block(C, N, dtype, max_block):
+    """Per lane: the double-buffered input tile, the network's live f32
+    copies of the power-of-two padded tile, and the double-buffered
+    (1, BLOCK) output."""
+    isz = jnp.dtype(dtype).itemsize
+    Cp = 1 << max(0, (C - 1).bit_length())
+    per_lane = (2 * tiling.padded_rows(C, isz) * isz
+                + _NETWORK_COPIES * tiling.padded_rows(Cp, 4) * 4
+                + 2 * tiling.padded_rows(1, isz) * isz)
+    return tiling.lane_block(N, per_lane, max_block=max_block)
 
 
 def _check_trim(C: int, trim: int):
@@ -174,14 +194,12 @@ def trimmed_mean_agg(stacked, trim: int, *, block=DEFAULT_BLOCK,
     """stacked: (C, N) client-stacked flat parameters. Returns the (N,)
     coordinate-wise mean of the order statistics with the `trim` smallest
     and `trim` largest per coordinate removed (trim=0 is the plain mean;
-    trim=(C-1)//2 is the median). Requires 0 <= 2*trim < C."""
+    trim=(C-1)//2 is the median). Requires 0 <= 2*trim < C. `block`
+    caps the lane block, which shrinks with C to fit VMEM."""
     C, N = stacked.shape
     _check_trim(C, trim)
     lo, hi = trim, C - trim
-    # scale the tile down with C so the network's live copies of the
-    # (C, BLOCK) tile stay well inside VMEM
-    block = min(block, max(128, _TILE_BUDGET // max(C, 1) // 128 * 128))
-    block = min(block, max(128, N))
+    block = _block(C, N, stacked.dtype, block)
     pad = (-N) % block
     if pad:
         stacked = jnp.pad(stacked, ((0, 0), (0, pad)))
@@ -191,11 +209,11 @@ def trimmed_mean_agg(stacked, trim: int, *, block=DEFAULT_BLOCK,
         functools.partial(_trimmed_kernel, lo=lo, hi=hi),
         grid=(Np // block,),
         in_specs=[pl.BlockSpec((C, block), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Np,), stacked.dtype),
+        out_specs=pl.BlockSpec((1, block), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, Np), stacked.dtype),
         interpret=interpret,
     )(stacked)
-    return out[:N]
+    return out[0, :N]
 
 
 def median_agg(stacked, *, block=DEFAULT_BLOCK, interpret=False):

@@ -1,9 +1,12 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax-importing module: jax locks
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST run before any jax-importing module: jax locks
 # the host platform device count at first init, and the dry-run needs 512
-# placeholder devices to build the production meshes. Everything else
-# (tests, benches, examples) sees the real single CPU device.
+# placeholder CPU devices to build the production meshes. It is pinned
+# to the CPU so that on a machine with an accelerator it neither builds
+# its meshes from the chips nor takes a chip another process holds.
+# Everything else (tests, benches, examples) sees the real devices.
 
 """Multi-pod AOT dry-run: lower + compile every (architecture x input
 shape) on the production meshes, and derive the roofline terms from the
@@ -31,7 +34,6 @@ from repro.configs.registry import combos, get_config
 from repro.launch import roofline as rl
 from repro.launch import serve as serve_mod
 from repro.launch import train as train_mod
-from repro.launch import mesh as mesh_mod
 from repro.launch.mesh import make_fl_mesh, make_production_mesh
 from repro.models.model import build_model
 from repro.optim import optimizers
@@ -198,7 +200,7 @@ def lower_and_compile(arch: str, shape_name: str, *, multi_pod=False,
               if shape.kind in ("train", "prefill") else shape.global_batch)
     flops_factor = 6.0 if shape.kind == "train" else 2.0
 
-    with mesh_mod.activate_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         lowered = _lower_step(cfg)
         t_lower = time.perf_counter() - t0
         compiled = lowered.compile()
@@ -314,7 +316,7 @@ def lower_fl(arch: str, strategy: str, *, multi_pod=False, seq_len=512,
     w_sds = jax.ShapeDtypeStruct((clients,), jnp.float32)
     part_sds = jax.ShapeDtypeStruct((clients,), jnp.bool_)
 
-    with mesh_mod.activate_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         lowered = jax.jit(trainer.fl_train_step, donate_argnums=(0,)).lower(
             state_sds, batch_sds, w_sds, part_sds)
         t_lower = time.perf_counter() - t0

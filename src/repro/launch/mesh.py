@@ -8,21 +8,7 @@ import; everything else sees the real (single) CPU device.
 from __future__ import annotations
 
 import jax
-
-
-def axis_types_kw(n: int) -> dict:
-    """`axis_types=(Auto,)*n` when this jax version has AxisType (>=0.6),
-    else empty — 0.4.x meshes are Auto-only and reject the kwarg."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n} if at is not None else {}
-
-
-def activate_mesh(mesh):
-    """Install `mesh` as the ambient mesh: `jax.sharding.set_mesh` on new
-    jax, the Mesh context manager on 0.4.x (same effect for Auto axes)."""
-    if hasattr(jax.sharding, "set_mesh"):
-        return jax.sharding.set_mesh(mesh)
-    return mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -30,7 +16,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     (pod=2, 16, 16) = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_fl_mesh(*, clients: int = 16, model: int = 16,
@@ -40,9 +27,9 @@ def make_fl_mesh(*, clients: int = 16, model: int = 16,
     and the "pod" axis carries HFL's hierarchy tier in multi-pod runs."""
     if multi_pod:
         return jax.make_mesh((2, clients, model), ("pod", "data", "model"),
-                             **axis_types_kw(3))
+                             axis_types=(AxisType.Auto,) * 3)
     return jax.make_mesh((clients, model), ("data", "model"),
-                         **axis_types_kw(2))
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def largest_divisor_at_most(n: int, k: int) -> int:
@@ -65,25 +52,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
     data = largest_divisor_at_most(n, data)
     model = largest_divisor_at_most(n // data, model)
     return jax.make_mesh((data, model), ("data", "model"),
-                         **axis_types_kw(2))
-
-
-def shard_map_compat(fn, mesh, *, in_specs, out_specs):
-    """`jax.shard_map` where it exists (>= 0.6), the experimental import
-    on 0.4.x — replication checking off under both spellings: the fused
-    scan derives local client ids from `axis_index` arithmetic, which
-    0.4.x's check_rep cannot type through `lax.scan` (the §11 parity
-    tests pin correctness instead)."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_client_mesh(devices: int = 0):
@@ -102,4 +71,4 @@ def make_client_mesh(devices: int = 0):
             f"mesh_devices={devices} exceeds the {n} available device(s) "
             f"(set XLA_FLAGS=--xla_force_host_platform_device_count=N "
             f"before importing jax for a CPU testbed)")
-    return jax.make_mesh((devices,), ("data",), **axis_types_kw(1))
+    return jax.make_mesh((devices,), ("data",), axis_types=(AxisType.Auto,))

@@ -1,10 +1,12 @@
 """Roofline-term derivation from AOT-compiled artifacts.
 
-Three terms per (arch x shape x mesh), in seconds (TPU v5e constants):
+Three terms per (arch x shape x mesh), in seconds, from the peaks of
+the target chip (`benchmarks/peaks.json`, keyed by `device_kind`; the
+dry-run targets TPU v5e: 197 TFLOP/s, 819 GB/s, 50 GB/s per link):
 
-    compute    = HLO_FLOPs / (chips * 197 TFLOP/s)
-    memory     = HLO_bytes / (chips * 819 GB/s)
-    collective = collective_link_bytes / (chips * 50 GB/s per link)
+    compute    = HLO_FLOPs / (chips * peak bf16 FLOP/s)
+    memory     = HLO_bytes / (chips * HBM bytes/s)
+    collective = collective_link_bytes / (chips * ICI bytes/s per link)
 
 `cost_analysis()` on an SPMD-partitioned executable reports *per-partition*
 numbers, so chips-normalization is already done for compute/memory; we
@@ -18,12 +20,33 @@ collective-permute ops, weighting all-reduce 2x (ring all-reduce moves
 from __future__ import annotations
 
 import dataclasses
+import functools
+import json
+import pathlib
 import re
-from typing import Dict, Optional
+from typing import Any, Dict, Optional
 
-PEAK_FLOPS = 197e12          # bf16 / chip (v5e)
-HBM_BW = 819e9               # bytes/s / chip
-ICI_BW = 50e9                # bytes/s / link
+# the one peak table, kept with the benchmarks
+PEAKS_FILE = (pathlib.Path(__file__).resolve().parents[3]
+              / "benchmarks" / "peaks.json")
+# `device_kind` of a TPU v5e chip: the chip the dry-run compiles for
+V5E = "TPU v5 lite"
+
+
+@functools.cache
+def _peak_table() -> Dict[str, Any]:
+    return json.loads(PEAKS_FILE.read_text())
+
+
+def device_peaks(kind: str) -> Dict[str, Any]:
+    """Published peaks of one chip of `kind` (a `Device.device_kind`).
+    Raises KeyError for a kind the table does not list."""
+    table = _peak_table()
+    if kind.startswith("_") or kind not in table:
+        raise KeyError(f"no published peaks for device kind {kind!r} in "
+                       f"{PEAKS_FILE}")
+    return table[kind]
+
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -79,15 +102,16 @@ class Roofline:
 
     @property
     def compute_s(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS
+        return self.flops_per_device / device_peaks(V5E)["bf16_flops"]
 
     @property
     def memory_s(self) -> float:
-        return self.bytes_per_device / HBM_BW
+        return self.bytes_per_device / device_peaks(V5E)["hbm_bytes_per_s"]
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes_per_device / ICI_BW
+        return (self.collective_bytes_per_device
+                / device_peaks(V5E)["ici_link_bytes_per_s"])
 
     @property
     def dominant(self) -> str:
@@ -112,8 +136,6 @@ class Roofline:
 
 def analyze(compiled, chips: int) -> Roofline:
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):
-        cost = cost[0]
     flops = float(cost.get("flops", 0.0))
     nbytes = float(cost.get("bytes accessed", 0.0))
     coll = parse_collective_bytes(compiled.as_text())

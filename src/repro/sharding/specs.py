@@ -66,17 +66,6 @@ def profile_ctx(profile: str):
         _PROFILE.reset(tok)
 
 
-def _axis_size(mesh, axis) -> int:
-    if axis is None:
-        return 1
-    if isinstance(axis, (tuple, list)):
-        n = 1
-        for a in axis:
-            n *= _axis_size(mesh, a)
-        return n
-    return dict(zip(mesh.axis_names, mesh.shape.values() if hasattr(mesh.shape, "values") else mesh.shape)).get(axis, mesh.shape[axis] if axis in mesh.axis_names else 1)
-
-
 def axis_size(mesh, axis) -> int:
     if axis is None:
         return 1

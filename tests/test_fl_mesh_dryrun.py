@@ -17,19 +17,18 @@ SNIPPET = textwrap.dedent("""
     import sys, json
     sys.path.insert(0, {src!r})
     import jax, jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
     from repro.configs.registry import get_config
     from repro.core.fl_types import FLConfig
     from repro.core.trainer import (FederatedTrainer, fl_tree_shardings,
                                     fl_tree_shardings_opt)
     from repro.models.model import build_model
     from repro.sharding import specs as sh
-    from repro.launch import mesh as mesh_mod
     from repro.launch import roofline as rl
 
     cfg = get_config("phi3-mini-3.8b").reduced().with_updates(vocab_size=512)
     mesh = jax.make_mesh((4, 2), ("data", "model"),
-                         **mesh_mod.axis_types_kw(2))
+                         axis_types=(AxisType.Auto,) * 2)
     fl = FLConfig(strategy="{strategy}", num_clients=4, num_groups=2,
                   local_steps=2, lr=0.05, afl_mode="{mode}")
     model = build_model(cfg)
@@ -54,7 +53,7 @@ SNIPPET = textwrap.dedent("""
                         bs, bsh)
     wsds = jax.ShapeDtypeStruct((4,), jnp.float32)
     psds = jax.ShapeDtypeStruct((4,), jnp.bool_)
-    with mesh_mod.activate_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         compiled = jax.jit(tr.fl_train_step).lower(
             ssds, bsds, wsds, psds).compile()
     coll = rl.parse_collective_bytes(compiled.as_text())
@@ -96,8 +95,7 @@ MESH_HFL_SNIPPET = textwrap.dedent("""
     sys.path.insert(0, {src!r})
     import numpy as np
     import jax, jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.core import aggregation as strategies
     from repro.core import topology
 
@@ -107,19 +105,21 @@ MESH_HFL_SNIPPET = textwrap.dedent("""
     weight = jnp.asarray(rng.uniform(10.0, 100.0, C).astype(np.float32))
     multi_pod = {multi_pod}
     if multi_pod:
-        mesh = jax.make_mesh((G, C // G), ("pod", "data"))
+        mesh = jax.make_mesh((G, C // G), ("pod", "data"),
+                             axis_types=(AxisType.Auto,) * 2)
         fn = lambda p, w: strategies.mesh_hfl(
             p, w[0], client_axis="data", pod_axis="pod")
         specs = (P(("pod", "data")), P(("pod", "data")))
         out_spec = P(("pod", "data"))
     else:
-        mesh = jax.make_mesh((C,), ("data",))
+        mesh = jax.make_mesh((C,), ("data",),
+                             axis_types=(AxisType.Auto,))
         fn = lambda p, w: strategies.mesh_hfl(
-            p, w[0], client_axis="data", num_groups=G,
-            force_fallback={fallback})
+            p, w[0], client_axis="data", num_groups=G)
         specs = (P("data"), P("data"))
         out_spec = P("data")
-    f = shard_map(fn, mesh=mesh, in_specs=specs, out_specs=out_spec)
+    f = jax.shard_map(fn, mesh=mesh, in_specs=specs, out_specs=out_spec,
+                      check_vma=False)
     out = np.asarray(jax.jit(f)(stacked, weight))
     replicated = bool(np.allclose(out, out[0:1], atol=1e-5))
 
@@ -132,16 +132,12 @@ MESH_HFL_SNIPPET = textwrap.dedent("""
 """)
 
 
-@pytest.mark.parametrize("groups,multi_pod,fallback", [
-    (2, False, False), (4, False, False), (2, True, False),
-    # pin BOTH tier-1 implementations (real axis_index_groups psum where
-    # the backend has it, and the one-hot-masked full psum) against the
-    # host — not just whichever one the installed jax picks
-    (2, False, True), (4, False, True),
+@pytest.mark.parametrize("groups,multi_pod", [
+    (2, False), (4, False), (2, True),
 ])
-def test_mesh_hfl_matches_host(groups, multi_pod, fallback):
+def test_mesh_hfl_matches_host(groups, multi_pod):
     code = MESH_HFL_SNIPPET.format(src=SRC, groups=groups,
-                                   multi_pod=multi_pod, fallback=fallback)
+                                   multi_pod=multi_pod)
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=900)
     assert out.returncode == 0, out.stderr[-3000:]
@@ -156,8 +152,8 @@ def test_mesh_hfl_matches_host(groups, multi_pod, fallback):
 # ---------------------------------------------------------------------------
 # The fused executor's general mesh operator: 16 clients over 8 shards
 # (2 clients per shard), exercising group sizes that nest inside a shard
-# (G=16), align exactly (G=8), and span multiple shards (G=4 — where the
-# grouped-psum / one-hot fallback split exists).
+# (G=16), align exactly (G=8), and span multiple shards (G=4 — the
+# grouped tier-1 psum).
 
 MESH_HFL_STACKED_SNIPPET = textwrap.dedent("""
     import os
@@ -180,12 +176,11 @@ MESH_HFL_STACKED_SNIPPET = textwrap.dedent("""
     def fn(p, w):
         # the global model has no client axis; re-tile each shard's copy
         # so the host side can check cross-shard replication
-        g = agg.mesh_hfl_stacked(p, w, G, axis="data",
-                                 force_fallback={fallback})
+        g = agg.mesh_hfl_stacked(p, w, G, axis="data")
         return g[None, :]
 
-    f = mesh_mod.shard_map_compat(
-        fn, mesh, in_specs=(P("data"), P("data")), out_specs=P("data"))
+    f = jax.shard_map(fn, mesh=mesh, in_specs=(P("data"), P("data")),
+                      out_specs=P("data"), check_vma=False)
     out = np.asarray(jax.jit(f)(stacked, weight))      # (8, N) shard copies
     replicated = bool(np.allclose(out, out[0:1], atol=1e-5))
 
@@ -197,16 +192,13 @@ MESH_HFL_STACKED_SNIPPET = textwrap.dedent("""
 """)
 
 
-@pytest.mark.parametrize("groups,fallback", [
-    (16, False),           # groups nest inside one shard (pure local tier 1)
-    (8, False),            # group == shard (the fused executor's regime)
-    (4, False),            # groups span 2 shards: grouped psum (or backend
-                           # fallback)
-    (4, True),             # groups span 2 shards: forced one-hot fallback
+@pytest.mark.parametrize("groups", [
+    16,                    # groups nest inside one shard (pure local tier 1)
+    8,                     # group == shard (the fused executor's regime)
+    4,                     # groups span 2 shards: grouped psum
 ])
-def test_mesh_hfl_stacked_matches_host(groups, fallback):
-    code = MESH_HFL_STACKED_SNIPPET.format(src=SRC, groups=groups,
-                                           fallback=fallback)
+def test_mesh_hfl_stacked_matches_host(groups):
+    code = MESH_HFL_STACKED_SNIPPET.format(src=SRC, groups=groups)
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=900)
     assert out.returncode == 0, out.stderr[-3000:]

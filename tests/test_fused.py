@@ -203,8 +203,13 @@ def test_fused_scenarios_registered_and_runnable():
 # memory-bounded chunked local training (ISSUE 6: FLConfig.fused_chunk)
 # ---------------------------------------------------------------------------
 # Clients are independent, so training the participant stack one
-# sub-stack at a time (lax.map over chunks) must be BITWISE equal to the
-# all-at-once vmap — chunking only bounds activation memory.
+# sub-stack at a time (lax.map over chunks) computes the same math as the
+# all-at-once stack — chunking only bounds activation memory. It is not
+# bitwise: the chunked round is a different XLA program (a loop over
+# (chunk, ...) operands), which XLA fuses and orders its float
+# reductions differently — the installed XLA:CPU moves the round loss by
+# ~2 ulp (2.4e-7 at loss 2.3). The pin is the fused-parity tolerance of
+# this file.
 
 @pytest.mark.parametrize("strategy,chunk,kw", [
     ("afl", 4, {}),
@@ -217,11 +222,11 @@ def test_fused_chunked_matches_unchunked(fused_ds, strategy, chunk, kw):
                                 fused_ds, strategy=strategy).run()
     chunked = FederatedSimulation(_cfg("fused", fused_chunk=chunk, **kw),
                                   fused_ds, strategy=strategy).run()
-    np.testing.assert_array_equal(chunked.round_train_loss,
-                                  whole.round_train_loss)
-    np.testing.assert_array_equal(chunked.round_test_acc,
-                                  whole.round_test_acc)
-    assert chunked.test_accuracy == whole.test_accuracy
+    np.testing.assert_allclose(chunked.round_train_loss,
+                               whole.round_train_loss, atol=1e-5)
+    np.testing.assert_allclose(chunked.round_test_acc,
+                               whole.round_test_acc, atol=1e-5)
+    assert abs(chunked.test_accuracy - whole.test_accuracy) <= 1e-5
 
 
 def test_fused_chunk_must_divide_stack(fused_ds):

@@ -105,18 +105,19 @@ TIER1_SNIPPET = textwrap.dedent("""
     def tier1(p, w):
         return agg.hfl_tier1_local(p, w, 1)        # 1 group per shard
 
-    f = mesh_mod.shard_map_compat(
-        tier1, mesh, in_specs=(P("data"), P("data")),
-        out_specs=(P("data"), P("data")))
+    f = jax.shard_map(
+        tier1, mesh=mesh, in_specs=(P("data"), P("data")),
+        out_specs=(P("data"), P("data")), check_vma=False)
     compiled = jax.jit(f).lower(stacked, weight).compile()
     tier1_coll = rl.parse_collective_bytes(compiled.as_text())["count"]
 
     # control: the FULL two-tier event on the same inputs must
     # communicate (tier 2's psum) — proving the parser sees collectives
     # in this HLO dialect at all
-    g = mesh_mod.shard_map_compat(
+    g = jax.shard_map(
         lambda p, w: agg.mesh_hfl_stacked(p, w, G, axis="data"),
-        mesh, in_specs=(P("data"), P("data")), out_specs=P())
+        mesh=mesh, in_specs=(P("data"), P("data")), out_specs=P(),
+        check_vma=False)
     compiled2 = jax.jit(g).lower(stacked, weight).compile()
     full_coll = rl.parse_collective_bytes(compiled2.as_text())["count"]
 

@@ -66,19 +66,18 @@ DRYRUN_SNIPPET = textwrap.dedent("""
     import sys, json
     sys.path.insert(0, {src!r})
     import jax, jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P, NamedSharding
+    from jax.sharding import AxisType, PartitionSpec as P, NamedSharding
     from repro.configs.registry import get_config
     from repro.models.model import build_model
     from repro.launch import train as tm, roofline as rl
     from repro.optim import optimizers
     from repro.sharding import specs as sh
-    from repro.launch import mesh as mesh_mod
 
     cfg = get_config("{arch}").reduced().with_updates(
         sharding_profile="{profile}", vocab_size=512)
     sh.set_profile(cfg.sharding_profile)
     mesh = jax.make_mesh((4, 2), ("data", "model"),
-                         **mesh_mod.axis_types_kw(2))
+                         axis_types=(AxisType.Auto,) * 2)
     model = build_model(cfg)
     params_shape = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     psh = sh.tree_shardings(params_shape, mesh)
@@ -97,7 +96,7 @@ DRYRUN_SNIPPET = textwrap.dedent("""
                                                           sharding=s),
                         bs, bsh)
     step = tm.make_train_step(model, opt)
-    with mesh_mod.activate_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         compiled = jax.jit(step).lower(psds, osds, bsds).compile()
     roof = rl.analyze(compiled, 8)
     print(json.dumps({{"ok": True,
@@ -144,13 +143,23 @@ def test_collective_parser():
     assert got["all-to-all"] == 16 * 16 * 4 + 4 * 4
 
 
+@pytest.mark.parametrize("kind", ["cpu", "TPU v4", "_doc"])
+def test_peak_table_refuses_unknown_device(kind):
+    """The roofline reads v5e peaks from the one table; any kind the
+    table does not list is an error, never a silent v5e default."""
+    from repro.launch import roofline as rl
+    assert rl.device_peaks(rl.V5E)["hbm_bytes_per_s"] == 819e9
+    with pytest.raises(KeyError):
+        rl.device_peaks(kind)
+
+
 DECODE_SHARD_SNIPPET = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import sys, json
     sys.path.insert(0, {src!r})
     import jax, jax.numpy as jnp
-    from repro.launch import mesh as mesh_mod
+    from jax.sharding import AxisType
     from repro.launch.serve import decode_state_shardings
 
     leaves = {{
@@ -175,8 +184,8 @@ DECODE_SHARD_SNIPPET = textwrap.dedent("""
         return out
 
     mm = jax.make_mesh((2, 4), ("data", "model"),
-                       **mesh_mod.axis_types_kw(2))
-    md = jax.make_mesh((8,), ("data",), **mesh_mod.axis_types_kw(1))
+                       axis_types=(AxisType.Auto,) * 2)
+    md = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
     print(json.dumps({{"model_mesh": dump(mm), "data_mesh": dump(md)}}))
 """)
 
