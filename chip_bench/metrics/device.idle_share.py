@@ -1,0 +1,9 @@
+"""Share of the traced window, in %, in which no operation ran on the
+device (mean over the cell's chips): 1 - busy / window."""
+
+
+def read(ctx):
+    t = ctx["trace"]
+    if not t or t["devices"] == 0 or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])

@@ -1,0 +1,341 @@
+"""Plain reference of the federations the cells run.
+
+Independent of the program: straight `jax.numpy` / `lax`, one model per
+client (`vmap` over clients), each convolution written out tap by tap,
+float32 at HIGHEST matmul precision, no kernels, no stacking tricks. It follows
+the paper CNN (arXiv:2512.10987 §2.4, Fig. 7) and the federation as the
+cell's configuration and traffic files state it:
+
+* data: the set the benchmark rendered, split IID — a seeded permutation
+  of the train indices cut into C contiguous parts (`np.array_split`),
+  each part sorted;
+* initial weights from `jax.random.PRNGKey(seed)`: four split keys, the
+  conv kernels N(0, 1)/sqrt(fan_in) in HWIO, the dense kernel
+  N(0, 1)/sqrt(490), zero biases;
+* per round, from `np.random.default_rng(seed)`: the participants (AFL:
+  `rng.choice` without replacement, sorted; CFL: a permutation that is
+  the visit order; HFL: every client), then per participant and epoch
+  one permutation of its shard, cut to whole batches;
+* local SGD with heavy-ball momentum (fresh per round), the mean
+  cross-entropy of each batch;
+* Byzantine clients (AFL): `attack_fraction` of the federation drawn
+  from `np.random.default_rng([seed, 0x5EEDA77C])`; sign-flip uploads
+  `base - scale * (local - base)`;
+* aggregation: sample-weighted mean (AFL), coordinate-wise median
+  (defense "median"), two-tier HFL (groups of contiguous clients, the
+  global tier weighted by group sizes, dissemination every
+  `hfl_global_every` rounds and at the last), the CFL continual merge
+  `(1 - alpha) model + alpha local` after each visit;
+* per round: the mean over participants of the mean loss of the last
+  epoch's batches, the mean accuracy of each trained local model on the
+  first min(512, smallest shard) samples of its own shard, and the
+  accuracy of the round model on the whole test set.
+
+`dtype=jnp.bfloat16` computes everything (data, weights, momentum,
+aggregation) in bfloat16 at the default precision: the lower-precision
+control.
+"""
+from __future__ import annotations
+
+import math
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+ATTACK_SALT = 0x5EEDA77C
+
+
+# -- model ------------------------------------------------------------------
+
+def init_params(seed, filters=(16, 12, 10), classes=10, image=(28, 28, 1)):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    p, cin = {}, image[2]
+    for i, cout in enumerate(filters):
+        k = jax.random.normal(ks[i], (3, 3, cin, cout)) / math.sqrt(9 * cin)
+        p[f"conv{i + 1}"] = {"kernel": k, "bias": jnp.zeros((cout,))}
+        cin = cout
+    feat = (image[0] // 4) * (image[1] // 4) * cin
+    p["head"] = {"kernel": (jax.random.normal(ks[3], (feat, classes))
+                            / math.sqrt(feat)),
+                 "bias": jnp.zeros((classes,))}
+    return p
+
+
+def conv_same(h, k, prec):
+    """Stride-1 SAME convolution, NHWC by HWIO, written out as the sum over
+    the kernel's taps of a shifted input times that tap's (cin, cout)
+    matrix. Under `vmap` over clients each tap is one batched matmul,
+    where a per-client `lax.conv` would become a grouped convolution."""
+    kh, kw = k.shape[0], k.shape[1]
+    H, W = h.shape[1], h.shape[2]
+    hp = jnp.pad(h, ((0, 0), (kh // 2, kh // 2), (kw // 2, kw // 2), (0, 0)))
+    out = 0.0
+    for i in range(kh):
+        for j in range(kw):
+            out = out + jnp.einsum("bhwc,co->bhwo",
+                                   hp[:, i:i + H, j:j + W, :], k[i, j],
+                                   precision=prec)
+    return out
+
+
+def forward(p, x, prec):
+    """x (B, 28, 28, 1) -> logits (B, 10)."""
+    def conv(q, h):
+        return jax.nn.relu(conv_same(h, q["kernel"], prec) + q["bias"])
+
+    def pool(h):
+        return lax.reduce_window(h, np.array(-np.inf, h.dtype), lax.max,
+                                 (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
+
+    h = pool(conv(p["conv1"], x))
+    h = pool(conv(p["conv2"], h))
+    h = conv(p["conv3"], h)
+    h = h.reshape(h.shape[0], -1)
+    return jnp.dot(h, p["head"]["kernel"], precision=prec) + p["head"]["bias"]
+
+
+def loss_fn(p, x, y, prec):
+    logp = jax.nn.log_softmax(forward(p, x, prec))
+    return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=1))
+
+
+def accuracy(p, x, y, prec):
+    return jnp.mean((jnp.argmax(forward(p, x, prec), -1) == y)
+                    .astype(jnp.float32))
+
+
+def local_sgd(p, xb, yb, lr, mom, prec):
+    """One client's local training over batches (T, B, ...): returns the
+    trained model and the loss of each batch before its step."""
+    def step(carry, batch):
+        q, mu = carry
+        loss, g = jax.value_and_grad(loss_fn)(q, batch[0], batch[1], prec)
+        mu = jax.tree.map(lambda m, gi: mom * m + gi, mu, g)
+        q = jax.tree.map(lambda a, m: a - lr * m, q, mu)
+        return (q, mu), loss
+
+    mu0 = jax.tree.map(jnp.zeros_like, p)
+    (p, _), losses = lax.scan(step, (p, mu0), (xb, yb))
+    return p, losses
+
+
+# -- jitted round pieces ----------------------------------------------------
+
+@partial(jax.jit, static_argnames=("prec", "block"))
+def train_clients(bases, x_dev, y_dev, gidx, lr, mom, *, prec, block):
+    """Every participant from its own base: bases (k, ...) stacked,
+    gidx (k, T, B) indices into the device train set. Runs `block`
+    clients at a time so the reference fits beside nothing else."""
+    k = gidx.shape[0]
+    split = lambda a: a.reshape((k // block, block) + a.shape[1:])  # noqa
+
+    def one_block(args):
+        b, gi = args
+        return jax.vmap(lambda q, g: local_sgd(q, x_dev[g], y_dev[g], lr,
+                                               mom, prec))(b, gi)
+
+    params, losses = lax.map(one_block,
+                             (jax.tree.map(split, bases), split(gidx)))
+    merge = lambda a: a.reshape((k,) + a.shape[2:])  # noqa
+    return jax.tree.map(merge, params), merge(losses)
+
+
+@partial(jax.jit, static_argnames=("prec",))
+def local_accuracy(params, x_dev, y_dev, eidx, *, prec):
+    """Each trained local model on its own eval shard: eidx (k, n)."""
+    return jax.vmap(lambda q, e: accuracy(q, x_dev[e], y_dev[e], prec))(
+        params, eidx)
+
+
+@partial(jax.jit, static_argnames=("prec", "block"))
+def test_accuracy(p, x, y, *, prec, block):
+    n = x.shape[0] // block * block
+    xs = x[:n].reshape((-1, block) + x.shape[1:])
+    ys = y[:n].reshape(-1, block)
+    hits = jnp.sum(lax.map(
+        lambda a: jnp.sum(jnp.argmax(forward(p, a[0], prec), -1) == a[1]),
+        (xs, ys)))
+    if n < x.shape[0]:
+        hits += jnp.sum(jnp.argmax(forward(p, x[n:], prec), -1) == y[n:])
+    return hits / x.shape[0]
+
+
+def weighted_mean(stack, w):
+    w = w / jnp.sum(w)
+    return jax.tree.map(lambda a: jnp.tensordot(w, a, axes=1), stack)
+
+
+def coordinate_median(stack):
+    return jax.tree.map(lambda a: jnp.median(a, axis=0).astype(a.dtype),
+                        stack)
+
+
+@partial(jax.jit, static_argnames=("prec",))
+def cfl_round(model, x_dev, y_dev, gidx, eidx, lr, mom, alpha, *, prec):
+    """One continual pass: visits in order, each trains from the carried
+    model and merges into it."""
+    def visit(m, args):
+        gi, ei = args
+        local, losses = local_sgd(m, x_dev[gi], y_dev[gi], lr, mom, prec)
+        acc = accuracy(local, x_dev[ei], y_dev[ei], prec)
+        m = jax.tree.map(lambda a, b: (1 - alpha) * a + alpha * b, m, local)
+        return m, (losses, acc)
+
+    return lax.scan(visit, model, (gidx, eidx))
+
+
+# -- schedule ----------------------------------------------------------------
+
+def partition(n_train, C, seed):
+    idx = np.random.default_rng(seed).permutation(n_train)
+    return [np.sort(p) for p in np.array_split(idx, C)]
+
+
+def attackers(C, fraction, seed):
+    if fraction <= 0 or C <= 1:
+        return np.zeros(C, bool)
+    k = min(C - 1, max(1, int(round(fraction * C))))
+    ids = np.random.default_rng([seed, ATTACK_SALT]).choice(C, size=k,
+                                                            replace=False)
+    mask = np.zeros(C, bool)
+    mask[ids] = True
+    return mask
+
+
+def schedule(fed, parts, seed):
+    """Per round: (participants in training order, (k, T, B) global train
+    indices of their batches)."""
+    rng = np.random.default_rng(seed)
+    C, B, E = fed["num_clients"], fed["local_batch_size"], \
+        fed.get("local_epochs", 1)
+    nb = min(len(p) for p in parts) // B
+    out = []
+    for _ in range(fed["rounds"]):
+        if fed["strategy"] == "afl":
+            k = max(1, int(round(fed.get("participation", 0.5) * C)))
+            pids = np.sort(rng.choice(C, size=k, replace=False))
+        elif fed["strategy"] == "cfl":
+            pids = rng.permutation(C)
+        else:
+            pids = np.arange(C)
+        g = np.empty((len(pids), E * nb, B), np.int32)
+        for i, c in enumerate(pids):
+            for e in range(E):
+                sel = rng.permutation(len(parts[c]))[: nb * B]
+                g[i, e * nb:(e + 1) * nb] = parts[c][sel].reshape(nb, B)
+        out.append((np.asarray(pids), g))
+    return out, nb
+
+
+# -- the federation ----------------------------------------------------------
+
+# what the reference implements; a cell that sets anything else needs
+# reference code of its own before it can be compared
+SUPPORTED = {"strategy": ("hfl", "afl", "cfl"), "defense": ("none", "median"),
+             "attack": ("none", "sign_flip"), "afl_mode": ("fedavg",),
+             "codec": ("none",), "fault_profile": ("none",)}
+DEFAULTS = {"defense": "none", "attack": "none", "afl_mode": "fedavg",
+            "codec": "none", "fault_profile": "none"}
+
+
+def run(spec, dataset, seed, *, dtype=jnp.float32, block=128):
+    """Run the cell's federation. Returns numpy results: round_loss,
+    round_train_acc, round_test_acc (R,), init and final global params
+    ({"conv1/kernel": array, ...})."""
+    fed, model = spec["federation"], spec["model"]
+    for key, allowed in SUPPORTED.items():
+        val = fed.get(key, DEFAULTS.get(key))
+        if val not in allowed or (fed["strategy"] == "cfl"
+                                  and fed.get("attack", "none") != "none"):
+            raise NotImplementedError(
+                f"the reference has no {key}={val!r} for strategy "
+                f"{fed['strategy']!r}")
+    prec = (lax.Precision.HIGHEST if dtype == jnp.float32
+            else lax.Precision.DEFAULT)
+    xtr, ytr = dataset["train"]
+    xte, yte = dataset["test"]
+    C = fed["num_clients"]
+    parts = partition(len(ytr), C, seed)
+    n_eval = min(512, min(len(p) for p in parts))
+    eval_rows = np.stack([p[:n_eval] for p in parts])
+    rounds, nb = schedule(fed, parts, seed)
+    x_dev = jnp.asarray(xtr, dtype)
+    y_dev = jnp.asarray(ytr)
+    x_test = jnp.asarray(xte, dtype)
+    y_test = jnp.asarray(yte)
+    cast = lambda t: jax.tree.map(lambda a: a.astype(dtype), t)  # noqa
+    init = init_params(seed, tuple(model["filters"]), model["classes"],
+                       tuple(model["image"]))
+    glob = cast(init)
+    lr = jnp.asarray(fed["lr"], dtype)
+    mom = jnp.asarray(fed["momentum"], dtype)
+    weights = jnp.asarray([len(p) for p in parts], dtype)
+    mask = attackers(C, fed.get("attack_fraction", 0.25), seed) \
+        if fed.get("attack", "none") != "none" else np.zeros(C, bool)
+    strategy = fed["strategy"]
+    G = fed.get("num_groups", 2)
+    groups = jax.tree.map(lambda a: jnp.stack([a] * G), glob)
+    losses, train_accs, test_accs = [], [], []
+    for ev, (pids, gidx) in enumerate(rounds):
+        k = len(pids)
+        gi = jnp.asarray(gidx)
+        ei = jnp.asarray(eval_rows[pids])
+        if strategy == "cfl":
+            glob, (ls, accs) = cfl_round(
+                glob, x_dev, y_dev, gi, ei, lr, mom,
+                jnp.asarray(fed.get("merge_alpha", 0.5), dtype), prec=prec)
+        else:
+            if strategy == "hfl":
+                per = C // G
+                bases = jax.tree.map(lambda a: jnp.repeat(a, per, axis=0),
+                                     groups)
+            else:
+                bases = jax.tree.map(lambda a: jnp.stack([a] * k), glob)
+            blk = block if k % block == 0 else k
+            params, ls = train_clients(bases, x_dev, y_dev, gi, lr, mom,
+                                       prec=prec, block=blk)
+            accs = local_accuracy(params, x_dev, y_dev, ei, prec=prec)
+            w = weights[jnp.asarray(pids)]
+            if strategy == "hfl":
+                tier1 = [weighted_mean(
+                    jax.tree.map(lambda a: a[g * per:(g + 1) * per], params),
+                    w[g * per:(g + 1) * per]) for g in range(G)]
+                groups = jax.tree.map(lambda *a: jnp.stack(a), *tier1)
+                gw = jnp.stack([jnp.sum(w[g * per:(g + 1) * per])
+                                for g in range(G)])
+                if ((ev + 1) % fed.get("hfl_global_every", 2) == 0
+                        or ev == fed["rounds"] - 1):
+                    glob = weighted_mean(groups, gw)
+                    groups = jax.tree.map(lambda a: jnp.stack([a] * G), glob)
+            else:
+                flags = jnp.asarray(mask[pids])
+                if fed.get("attack", "none") == "sign_flip":
+                    s = jnp.asarray(fed.get("attack_scale", 1.0), dtype)
+                    params = jax.tree.map(
+                        lambda l, b: jnp.where(
+                            flags.reshape((k,) + (1,) * (l.ndim - 1)),
+                            b - s * (l - b), l), params, bases)
+                if fed.get("defense", "none") == "median":
+                    glob = coordinate_median(params)
+                else:
+                    glob = weighted_mean(params, w)
+        losses.append(float(jnp.mean(ls[:, -nb:].astype(jnp.float32))))
+        train_accs.append(float(jnp.mean(accs)))
+        test_accs.append(float(test_accuracy(
+            glob, x_test, y_test, prec=prec, block=min(2000, len(yte)))))
+    return {"round_loss": np.asarray(losses),
+            "round_train_acc": np.asarray(train_accs),
+            "round_test_acc": np.asarray(test_accs),
+            "init": flat(init), "final": flat(glob)}
+
+
+def flat(tree):
+    """{"conv1/kernel": float32 numpy array, ...}."""
+    out = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        name = "/".join(str(getattr(k, "key", k)) for k in path)
+        out[name] = np.asarray(jax.device_get(leaf), np.float32)
+    return out
