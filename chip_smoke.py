@@ -38,6 +38,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import re
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -127,16 +128,16 @@ def check_learns(name, res):
                              f"is not above chance ({CHANCE})")
 
 
-def check_native(name, sim, res, counters):
-    """The run's compiled scan holds a Pallas kernel, and each named
-    kernel wrapper was traced into it."""
-    if "tpu_custom_call" not in sim.fused_program.as_text():
-        raise AssertionError(f"{name}: no Pallas kernel in the fused scan")
-    dispatch = res.extra["telemetry"]["dispatch"]
-    for c in counters:
-        if not dispatch.get(c, 0) > 0:
-            raise AssertionError(f"{name}: counter {c} is zero "
-                                 f"({dispatch})")
+def check_native(name, sim, kernels):
+    """The run's compiled scan holds each named Pallas kernel: an
+    instruction named after the kernel's jitted wrapper
+    (`%fedavg_agg.3 = ... custom-call(...)`) that calls
+    `tpu_custom_call`."""
+    lines = sim.fused_program.as_text().splitlines()
+    for k in kernels:
+        pat = re.compile(r"%" + k + r"(\.\d+)? = .*tpu_custom_call")
+        if not any(pat.search(line) for line in lines):
+            raise AssertionError(f"{name}: no {k} kernel in the fused scan")
 
 
 def max_diff(a, b):
@@ -179,7 +180,7 @@ def phase_a(ds, dev, seed):
         sim, res = run(ds, strategy=strategy, seed=seed)
         check_learns(f"A/{strategy}", res)
         if strategy != "cfl":
-            check_native(f"A/{strategy}", sim, res, ["kernel.fedavg_agg"])
+            check_native(f"A/{strategy}", sim, ["fedavg_agg"])
         fused[strategy] = res
         report(f"A/{strategy}/fused", dev, res)
     model_dim = sim.model_dim
@@ -202,21 +203,21 @@ def phase_a(ds, dev, seed):
 def phase_b(ds, dev, seed, model_dim):
     """Every FL kernel natively on the device: three inside the fused
     scan, and each called directly against its jnp reference."""
-    for name, kw, counters in [
+    for name, kw, kernels in [
         ("median", dict(strategy="afl", participation=1.0,
                         attack="sign_flip", defense="median"),
-         ["kernel.trimmed_mean"]),
+         ["trimmed_mean_agg"]),
         ("gossip_churn", dict(strategy="afl", participation=1.0,
                               afl_mode="gossip", fault_profile="churn"),
-         ["kernel.gossip_mix"]),
+         ["gossip_mix_agg"]),
         ("qsgd", dict(strategy="hfl", codec="qsgd", quant_bits=8,
-                      rounds=1), ["kernel.fedavg_agg"]),
+                      rounds=1), ["fedavg_agg"]),
     ]:
         sim, res = run(ds, seed=seed, **kw)
         losses = np.asarray(res.round_train_loss)
         if not np.all(np.isfinite(losses)):
             raise AssertionError(f"B/{name}: non-finite losses {losses}")
-        check_native(f"B/{name}", sim, res, counters)
+        check_native(f"B/{name}", sim, kernels)
         report(f"B/{name}", dev, res)
     diffs = kernel_parity(CLIENTS, model_dim, seed)
     print(json.dumps({"phase": "B/kernel_parity", "device_kind":
@@ -233,7 +234,7 @@ def phase_c(ds, dev, seed, model_dim):
     losses = np.asarray(res.round_train_loss)
     if not np.all(np.isfinite(losses)):
         raise AssertionError(f"C: non-finite losses {losses}")
-    check_native("C", sim, res, ["kernel.fedavg_agg"])
+    check_native("C", sim, ["fedavg_agg"])
     report(f"C/afl_{BIG_CLIENTS}c_chunk{BIG_CHUNK}", dev, res)
     diffs = kernel_parity(BIG_CLIENTS, model_dim, seed)
     print(json.dumps({"phase": "C/kernel_parity", "device_kind":

@@ -290,28 +290,20 @@ def main():
 
 def _print_phase_table(tel):
     """The per-phase time breakdown from the result document's
-    telemetry block (DESIGN.md §13): steady-state lifecycle phases
-    first, then the fused executor's per-phase device-time proxy when
-    the run produced one."""
+    telemetry block (DESIGN.md §13): the steady-state lifecycle phases
+    (host dispatch windows of the per-round drivers; the fused engine's
+    phases run inside one compiled scan and show in an `--xla-profile`
+    trace under their named scopes instead)."""
     if not tel or not tel.get("enabled"):
         return
-    proxy = tel.get("fused_phase_proxy") or {}
-    # drop the proxy's container spans — only the lifecycle phases
-    # nested inside them belong in the breakdown
-    proxy = {k: v for k, v in proxy.items()
-             if k not in ("fused_phase_proxy", "round")}
-    for title, block in (("phase breakdown (host dispatch):",
-                          tel.get("phases")),
-                         ("fused per-phase proxy (device time, 1 round):",
-                          proxy)):
-        if not block:
-            continue
-        total = sum(e["total_s"] for e in block.values()) or 1.0
-        print(title)
-        for name, e in sorted(block.items(),
-                              key=lambda kv: -kv[1]["total_s"]):
-            print(f"   {name:18s} {e['total_s']:8.3f}s "
-                  f"x{e['count']:<4d} ({100 * e['total_s'] / total:5.1f}%)")
+    block = tel.get("phases")
+    if not block:
+        return
+    total = sum(e["total_s"] for e in block.values()) or 1.0
+    print("phase breakdown (host dispatch):")
+    for name, e in sorted(block.items(), key=lambda kv: -kv[1]["total_s"]):
+        print(f"   {name:18s} {e['total_s']:8.3f}s "
+              f"x{e['count']:<4d} ({100 * e['total_s'] / total:5.1f}%)")
 
 
 if __name__ == "__main__":

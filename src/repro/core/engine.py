@@ -48,7 +48,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import cnn as cnn_mod
-from repro.obs import telemetry
 from repro.optim import optimizers
 
 Params = Any
@@ -281,6 +280,10 @@ def cfl_round_scan(model, data, eval_images, eval_labels, alpha, *,
     declared degraded action for the redundancy-1 sequential merge).
     Both None is the exact pre-fault traced program.
 
+    Each visit's phases run under `jax.named_scope` (`local_train`,
+    `local_eval`, `corrupt`, `encode_decode`, `aggregate`), the names
+    of the per-round driver's phase spans; scopes are metadata only.
+
     Returns (final model, losses (C, T), post-train local accs (C,))."""
     from repro.core import aggregation, attacks, codecs  # deferred
     opt = optimizers.sgd(lr, momentum=momentum)
@@ -317,25 +320,31 @@ def cfl_round_scan(model, data, eval_images, eval_labels, alpha, *,
         if fault_alive is not None:
             av = inputs[off]
             off += 1
-        local, losses, _ = _local_sgd_scan(model, cdata, opt, loss_fn)
-        preds = jnp.argmax(apply_fn(local, ex), axis=-1)
-        acc = jnp.mean((preds == ey).astype(jnp.float32))
+        with jax.named_scope("local_train"):
+            local, losses, _ = _local_sgd_scan(model, cdata, opt, loss_fn)
+        with jax.named_scope("local_eval"):
+            preds = jnp.argmax(apply_fn(local, ex), axis=-1)
+            acc = jnp.mean((preds == ey).astype(jnp.float32))
         if attack not in ("none", "label_flip"):
-            local = attacks.corrupt_tree(local, model, flag, key,
-                                         kind=attack, scale=attack_scale)
+            with jax.named_scope("corrupt"):
+                local = attacks.corrupt_tree(local, model, flag, key,
+                                             kind=attack,
+                                             scale=attack_scale)
         if codec is not None:
-            local = codecs.roundtrip_tree(codec, local, ckey[None],
-                                          base_tree=model)
-        if defense == "norm_clip":
-            merged = aggregation.defended_cfl_merge(model, local, alpha,
-                                                    clip_tau)
-        else:
-            merged = aggregation.cfl_merge_stacked(model, local, alpha)
-        if fault_alive is not None:
-            # a dead visitor's merge is discarded (upload lost on the
-            # wire); the carried model passes through bitwise, matching
-            # the loop engine's skipped host merge
-            merged = aggregation.tree_where(av > 0, merged, model)
+            with jax.named_scope("encode_decode"):
+                local = codecs.roundtrip_tree(codec, local, ckey[None],
+                                              base_tree=model)
+        with jax.named_scope("aggregate"):
+            if defense == "norm_clip":
+                merged = aggregation.defended_cfl_merge(model, local,
+                                                        alpha, clip_tau)
+            else:
+                merged = aggregation.cfl_merge_stacked(model, local, alpha)
+            if fault_alive is not None:
+                # a dead visitor's merge is discarded (upload lost on
+                # the wire); the carried model passes through bitwise,
+                # matching the loop engine's skipped host merge
+                merged = aggregation.tree_where(av > 0, merged, model)
         return merged, (losses, acc)
 
     model0 = model
@@ -349,8 +358,9 @@ def cfl_round_scan(model, data, eval_images, eval_labels, alpha, *,
     if fault_qok is not None:
         # below-quorum round: the declared degraded action holds the
         # whole round at its start model
-        model = aggregation.tree_where(jnp.asarray(fault_qok, bool),
-                                       model, model0)
+        with jax.named_scope("aggregate"):
+            model = aggregation.tree_where(jnp.asarray(fault_qok, bool),
+                                           model, model0)
     return model, losses, accs
 
 
@@ -482,7 +492,6 @@ class VectorizedClientEngine:
         exclusively (see `train_clients_donated`) so the trained
         parameters reuse those buffers instead of doubling the
         federation's peak memory."""
-        telemetry.count("engine.train_dispatch")
         return train_clients_donated(
             stacked_params, data,
             stacked_loss_fn=stacked_loss_fn or self.stacked_loss_fn,
@@ -501,7 +510,6 @@ class VectorizedClientEngine:
                   attack_scale=1.0, attack_flags=None, attack_keys=None,
                   defense="none", clip_tau=10.0, codec=None,
                   codec_keys=None, fault_alive=None, fault_qok=None):
-        telemetry.count("engine.cfl_round_dispatch")
         idx = jnp.asarray(np.asarray(order))
         return cfl_round_scan(model, data, self.eval_x[idx], self.eval_y[idx],
                               alpha, loss_fn=self.loss_fn,

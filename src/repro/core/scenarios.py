@@ -37,7 +37,11 @@ from repro.core.fl_types import ARRIVALS, ATTACKS, DEFENSES
 from repro.core.strategies import (STRATEGY_REGISTRY_VERSION, get_strategy,
                                    strategy_names)
 
-# v2.5: adds the "faults" block (churn-tolerant runtime — DESIGN.md §15:
+# v2.6: the "telemetry" block loses the per-round phase proxy and the
+# trace-entry dispatch counters, its counters gain the fused executor's
+# "compile.requests" / "compile.cache_hits", and its "run" spans gain
+# construct / lower / compile (DESIGN.md §13). v2.5 added the "faults" block (churn-tolerant
+# runtime — DESIGN.md §15:
 # fault profile + schedule statistics, churn/rejoin counts, quorum
 # failures, degraded rounds; null when fault_profile="none"). v2.4
 # added the "serving" block (federation-in-the-loop serving —
@@ -52,7 +56,7 @@ from repro.core.strategies import (STRATEGY_REGISTRY_VERSION, get_strategy,
 # registry version; null for dense runs); v2.1 added the "strategy"
 # block (plugin name + registry version); v2 added the "attack" block.
 # Older documents are still readable through `load_result`.
-RESULT_SCHEMA_VERSION = 2.5
+RESULT_SCHEMA_VERSION = 2.6
 
 # One output-dir convention for every result/curve writer: the example
 # CLI's curves, `--json` grid dumps, and experiment artifacts all land
@@ -678,10 +682,13 @@ def load_result(doc: Dict) -> Dict:
     no "telemetry" block — they read as untraced runs; v2.3 documents
     (pre-serving) carry no "serving" block — they read as train-only
     runs; v2.4 documents (pre-faults) carry no "faults" block — they
-    read as fault-free runs."""
+    read as fault-free runs; v2.3-v2.5 telemetry blocks keep the two
+    keys v2.6 no longer writes, which no consumer reads."""
     v = doc.get("schema_version")
     if v == RESULT_SCHEMA_VERSION:
         return doc
+    if v == 2.5:
+        return {**doc, "schema_version": RESULT_SCHEMA_VERSION}
     if v == 2.4:
         return {**doc, "schema_version": RESULT_SCHEMA_VERSION,
                 "faults": None}

@@ -288,9 +288,18 @@ class FederatedSimulation:
         self.fl = fl
         self.dataset = dataset
         self.rng = np.random.default_rng(fl.seed)
-        # per-run tracer (DESIGN.md §13); dispatch counters are
-        # snapshotted at construction so the run's delta is its own
-        self.telemetry = Telemetry(enabled=fl.telemetry)
+        # per-run tracer (DESIGN.md §13); its spans also enter
+        # `prog.<name>` annotations, so they land in any jax.profiler
+        # trace beside the device ops
+        self.telemetry = Telemetry(enabled=fl.telemetry,
+                                   annotate=jax.profiler.TraceAnnotation)
+        with self.telemetry.span("construct", cat="run"):
+            self._construct(model_init, strategy)
+
+    def _construct(self, model_init, strategy):
+        """Everything `__init__` builds after the tracer: strategy,
+        codec, fault schedule, attackers, partition and client shards."""
+        fl, dataset = self.fl, self.dataset
         key = jax.random.PRNGKey(fl.seed)
         self.init_params = (model_init or cnn_mod.init_cnn)(key)
         # resolve the strategy plugin: an instance is used as-is (plugin
@@ -462,16 +471,6 @@ class FederatedSimulation:
                     if self.fl.engine in ("vectorized", "fused") else None)
 
     # -- driver primitives (the plugin-facing surface) ----------------------
-    def tel_sync(self, x):
-        """Telemetry phase boundary: under the fused per-phase proxy
-        (`Telemetry.sync_active`) block until `x`'s device work finishes,
-        so the enclosing span measures device time. A no-op in steady
-        state — spans there deliberately measure dispatch windows only
-        (the ≤5% overhead budget, DESIGN.md §13). Returns `x`."""
-        if self.telemetry.sync_active:
-            jax.block_until_ready(x)
-        return x
-
     def defense_kwargs(self, event_size=None) -> Dict[str, Any]:
         """kwargs for the defended aggregation operators, with the
         Byzantine allowance resolved for this event's client count."""
@@ -534,7 +533,6 @@ class FederatedSimulation:
                     losses.append(loss)
                     accs.append(acc)
                 out = (engine_mod.stack_forest(locals_), losses, accs)
-            self.tel_sync(out[0])
         return out
 
     def corrupt(self, uploads, plan):
@@ -554,7 +552,6 @@ class FederatedSimulation:
             out = attacks.corrupt_stacked(uploads, bases, flags, keys,
                                           kind=fl.attack,
                                           scale=fl.attack_scale)
-            self.tel_sync(out)
         return out
 
     def transport(self, uploads, plan):
@@ -591,7 +588,6 @@ class FederatedSimulation:
                 "codec.uplink_bytes",
                 len(plan.participants) * codec.bytes_on_wire(self.model_dim))
             out = ops.stacked_unravel(uploads, dec)
-            self.tel_sync(out)
         return out
 
     def fault_view(self, plan):
@@ -625,7 +621,6 @@ class FederatedSimulation:
         with self.telemetry.span("sequential_round", k=len(order)):
             out = self._sequential_round(model, order, event, alpha,
                                          spec, rng)
-            self.tel_sync(out[0])
         return out
 
     def _sequential_round(self, model, order, event, alpha, spec, rng):
@@ -951,20 +946,19 @@ class FederatedSimulation:
             run_fn, carry0, xs, consts = self._mesh_wrap(
                 _run, carry0, xs, consts, pids)
 
-        # warmup = compile the scan once (AOT, so the donated carry is
-        # not consumed) + the classification-phase predict shapes
+        # warmup = lower + compile the scan once (AOT, so the donated
+        # carry is not consumed) + the classification-phase predict
+        # shapes; lowering and compiling are spans of their own, which
+        # count the run's compile requests and persistent-cache hits
         warmup_timer = Timer()
-        with tel.span("warmup", cat="run"), warmup_timer, tel.suppress():
-            compiled = jax.jit(run_fn, donate_argnums=(0,)).lower(
-                carry0, xs, consts).compile()
-            self._warmup_predicts()
-        # per-phase device-time proxy (obs/collectors.py): one
-        # instrumented per-round event, every phase blocking on its
-        # device work. Skipped when chunked (the per-round path would
-        # materialize the UNCHUNKED participant stack) or meshed.
-        if tel.enabled and not fl.fused_chunk and mesh_axis is None:
-            obs_collectors.fused_phase_proxy(self)
-            self._reset_codec()
+        with tel.span("warmup", cat="run"), warmup_timer:
+            with obs_collectors.compile_span(tel, "lower"):
+                lowered = jax.jit(run_fn, donate_argnums=(0,)).lower(
+                    carry0, xs, consts)
+            with obs_collectors.compile_span(tel, "compile"):
+                compiled = lowered.compile()
+            with tel.suppress():
+                self._warmup_predicts()
 
         build_timer = Timer()
         with build_timer, tel.span("fused_scan", cat="run", rounds=R):
@@ -1193,8 +1187,9 @@ class FederatedSimulation:
             # consumers see the documented loop/vectorized divergence
             extra["truncated_samples_per_epoch"] = dict(
                 self.vec.dropped_samples)
-        # the schema-v2.3 telemetry block (always present; when disabled
-        # it is the single-key {"enabled": False} stub)
+        # the telemetry block (schema v2.3, reshaped in v2.6; always
+        # present; when disabled it is the single-key {"enabled": False}
+        # stub)
         extra["telemetry"] = obs_export.result_block(self.telemetry)
 
         return FLResult(

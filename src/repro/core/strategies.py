@@ -232,7 +232,6 @@ class Strategy:
             uploads = sim.transport(uploads, plan)
             with tel.span("aggregate", event=event, **fargs):
                 state = self.aggregate_event(sim, state, plan, uploads)
-                sim.tel_sync(state)
         return state, accs, losses
 
     def _fault_telemetry(self, sim, plan) -> Dict[str, Any]:
@@ -384,25 +383,37 @@ class Strategy:
         `fx.local_pids` maps the absolute participant ids to local rows,
         training/corruption run unchanged per shard, and the per-round
         scalar metrics are pmean'd so every shard reports the federation
-        mean (equal shard sizes make the mean of shard means exact)."""
+        mean (equal shard sizes make the mean of shard means exact).
+
+        Each phase runs under a `jax.named_scope` named after the
+        per-round driver's phase span (`local_train`, `local_eval`,
+        `corrupt`, `encode_decode`, `aggregate`, `eval`), so its ops
+        carry the phase in their `op_name` metadata and a profiler trace
+        can attribute device time to it. Scopes are metadata only."""
         fl = fx.fl
         bases = self.scan_bases(fx, carry, xs)
         pids = fx.local_pids(xs["pids"])
-        batch = engine_mod.gather_batches(fx.data_x, fx.data_y,
-                                          pids, xs["idx"])
         spec = self.local_spec(fx.sim, None, None)
         extra = bases if spec.extra == "bases" else None
-        params, losses, _ = engine_mod._train_clients_chunked_impl(
-            bases, batch, stacked_loss_fn=spec.stacked_loss_fn,
-            lr=fl.lr, momentum=fl.momentum, extra=extra,
-            chunk=fl.fused_chunk)
-        accs = fx.local_accs(params, pids)
-        uploads = fx.corrupt(params, bases, xs)
-        uploads = fx.transport(uploads, bases, xs)
-        carry = self.scan_aggregate(fx, carry, xs, uploads)
+        with jax.named_scope("local_train"):
+            batch = engine_mod.gather_batches(fx.data_x, fx.data_y,
+                                              pids, xs["idx"])
+            params, losses, _ = engine_mod._train_clients_chunked_impl(
+                bases, batch, stacked_loss_fn=spec.stacked_loss_fn,
+                lr=fl.lr, momentum=fl.momentum, extra=extra,
+                chunk=fl.fused_chunk)
+        with jax.named_scope("local_eval"):
+            accs = fx.local_accs(params, pids)
+        with jax.named_scope("corrupt"):
+            uploads = fx.corrupt(params, bases, xs)
+        with jax.named_scope("encode_decode"):
+            uploads = fx.transport(uploads, bases, xs)
+        with jax.named_scope("aggregate"):
+            carry = self.scan_aggregate(fx, carry, xs, uploads)
+        with jax.named_scope("eval"):
+            test_acc = fx.test_acc(self.round_model(carry))
         return carry, (fx.pmean(jnp.mean(accs)),
-                       fx.pmean(jnp.mean(losses[:, -fx.nb:])),
-                       fx.test_acc(self.round_model(carry)))
+                       fx.pmean(jnp.mean(losses[:, -fx.nb:])), test_acc)
 
     def scan_telemetry(self, fx, carry, new_carry, xs) -> Dict[str, Any]:
         """Strategy-specific in-scan per-round counters (traceable;
@@ -974,9 +985,12 @@ class CFLStrategy(Strategy):
     supports_fused = True
 
     def scan_round(self, fx, carry, xs):
+        # named scopes as in `Strategy.scan_round`; `cfl_round_scan`
+        # scopes each visit's phases itself
         fl = self.fl
-        batch = engine_mod.gather_batches(fx.data_x, fx.data_y,
-                                          xs["pids"], xs["idx"])
+        with jax.named_scope("local_train"):
+            batch = engine_mod.gather_batches(fx.data_x, fx.data_y,
+                                              xs["pids"], xs["idx"])
         model, losses, accs = engine_mod.cfl_round_scan(
             carry["model"], batch, fx.eval_x[xs["pids"]],
             fx.eval_y[xs["pids"]], fl.merge_alpha,
@@ -988,9 +1002,10 @@ class CFLStrategy(Strategy):
             codec_keys=xs.get("ckeys"),
             fault_alive=xs.get("fault_alive"),
             fault_qok=xs.get("fault_qok"))
-        carry = {"model": model}
-        return carry, (jnp.mean(accs), jnp.mean(losses[:, -fx.nb:]),
-                       fx.test_acc(model))
+        with jax.named_scope("eval"):
+            test_acc = fx.test_acc(model)
+        return {"model": model}, (jnp.mean(accs),
+                                  jnp.mean(losses[:, -fx.nb:]), test_acc)
 
 
 # ---------------------------------------------------------------------------

@@ -19,7 +19,6 @@ from repro.kernels import gossip_mix as _gm
 from repro.kernels import robust_agg as _ra
 from repro.kernels import ssm_scan as _ss
 from repro.kernels import ref
-from repro.obs import telemetry
 
 
 @functools.cache
@@ -30,7 +29,6 @@ def on_cpu() -> bool:
 # -- fedavg ------------------------------------------------------------------
 
 def fedavg_aggregate(stacked, weights, *, interpret=None):
-    telemetry.count("kernel.fedavg_agg")
     interpret = on_cpu() if interpret is None else interpret
     return _fa.fedavg_agg(stacked, weights, interpret=interpret)
 
@@ -43,7 +41,6 @@ def fedavg_aggregate(stacked, weights, *, interpret=None):
 # kernel with interpret=True.
 
 def dequant_aggregate(values, scales, weights, *, interpret=None):
-    telemetry.count("kernel.dequant_agg")
     if interpret is None and on_cpu():
         return _ca.dequant_agg_jnp(values, scales, weights)
     return _ca.dequant_agg(values, scales, weights,
@@ -60,7 +57,6 @@ def dequant_aggregate(values, scales, weights, *, interpret=None):
 # Pallas kernel with interpret=True.
 
 def masked_gossip_aggregate(stacked, mix, *, interpret=None):
-    telemetry.count("kernel.gossip_mix")
     if interpret is None and on_cpu():
         return _gm.gossip_mix_jnp(stacked, mix)
     return _gm.gossip_mix_agg(stacked, mix, interpret=bool(interpret))
@@ -78,7 +74,6 @@ def masked_gossip_aggregate(stacked, mix, *, interpret=None):
 # the vectorized network at C=64.
 
 def trimmed_mean_aggregate(stacked, trim, *, interpret=None):
-    telemetry.count("kernel.trimmed_mean")
     if interpret is None and on_cpu():
         return _ra.trimmed_mean_jnp(stacked, trim)
     return _ra.trimmed_mean_agg(stacked, trim,
@@ -169,7 +164,6 @@ def flash_attention(q, k, v, *, causal=True, window=0, interpret=None,
     """q: (B,S,H,d); k/v: (B,T,Hk,d) — GQA folded by repeating KV heads.
 
     Returns (B,S,H,d)."""
-    telemetry.count("kernel.flash_attention")
     interpret = on_cpu() if interpret is None else interpret
     B, S, H, d = q.shape
     Hk = k.shape[2]
@@ -189,7 +183,6 @@ def flash_attention(q, k, v, *, causal=True, window=0, interpret=None,
 # -- ssm scan ------------------------------------------------------------------
 
 def ssm_scan(xh, a_log, dt, Bm, Cm, *, chunk=128, interpret=None):
-    telemetry.count("kernel.ssm_scan")
     interpret = on_cpu() if interpret is None else interpret
     return _ss.ssm_scan(xh, a_log, dt, Bm, Cm, chunk=chunk,
                         interpret=interpret)

@@ -1,8 +1,8 @@
-"""Device-resident in-scan counters + the fused per-phase timing proxy
-(DESIGN.md §13).
+"""Device-side telemetry collectors (DESIGN.md §13): in-scan counters
+for the fused executor, and compile counters credited to one run.
 
 Host spans cannot see inside the fused executor's compiled R-round
-`lax.scan` (DESIGN.md §10), so fused-engine telemetry has two pieces:
+`lax.scan` (DESIGN.md §10), so fused-engine telemetry adds:
 
 * `round_counters` — per-round scalar accumulators traced INTO the scan
   body: they ride the scan's stacked outputs next to the metric curves
@@ -11,25 +11,61 @@ Host spans cannot see inside the fused executor's compiled R-round
   add their own through `Strategy.scan_telemetry` (model-delta L2 by
   default, HFL adds the group-spread L2).
 
-* `fused_phase_proxy` — per-phase DEVICE timings via block_until_ready
-  segmentation at warmup: one throwaway per-round event runs under
-  `Telemetry.category("proxy")`, where every lifecycle phase blocks on
-  its device work (`FederatedSimulation.tel_sync`), so the recorded
-  span durations approximate the in-scan per-phase cost. The event runs
-  twice — first suppressed (compiling the per-round programs the fused
-  run otherwise never compiles), then measured — with a throwaway rng,
-  so `sim.rng` and the measured scan are untouched. The driver skips
-  the proxy when `fused_chunk > 0` (the proxy would materialize the
-  UNCHUNKED participant stack and blow the memory envelope chunking
-  exists to bound) and under the mesh path (the per-round programs are
-  single-device).
+* `compile_span` — a run-level span around lowering or compiling the
+  scan, inside which JAX's compilation-cache monitoring events count
+  into the run's `compile.requests` / `compile.cache_hits` counters
+  (misses = requests - hits). JAX keeps event listeners for the life of
+  the process, so one listener is registered per process; each event is
+  credited to the `Telemetry` whose `compile_span` is open on the
+  compiling thread. JAX records a request for every XLA compile while
+  `jax_enable_compilation_cache` is on (its default), with or without a
+  cache directory; a hit needs the directory.
+
+Per-phase device time inside the scan is the XLA profiler's: the round's
+phases run under `jax.named_scope` (`Strategy.scan_round`), so their ops
+carry the phase name in their `op_name` metadata.
 """
 from __future__ import annotations
 
+import contextlib
+import threading
 from typing import Any, Dict
 
+import jax
 import jax.numpy as jnp
-import numpy as np
+
+COMPILE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "compile.requests",
+    "/jax/compilation_cache/cache_hits": "compile.cache_hits",
+}
+
+_open = threading.local()          # per thread: Telemetry stack
+_listener_lock = threading.Lock()
+_listening = False
+
+
+def _on_event(event: str, **_) -> None:
+    counter = COMPILE_EVENTS.get(event)
+    stack = getattr(_open, "stack", None)
+    if counter is not None and stack:
+        stack[-1].counter(counter)
+
+
+@contextlib.contextmanager
+def compile_span(tel, name: str):
+    """A "run" span `name` during which compile events count on `tel`."""
+    global _listening
+    with _listener_lock:
+        if not _listening:
+            jax.monitoring.register_event_listener(_on_event)
+            _listening = True
+    stack = _open.__dict__.setdefault("stack", [])
+    stack.append(tel)
+    try:
+        with tel.span(name, cat="run"):
+            yield
+    finally:
+        stack.pop()
 
 
 def round_counters(strat, fx, carry_prev, carry_new, xs
@@ -45,20 +81,3 @@ def round_counters(strat, fx, carry_prev, carry_new, xs
     for k, v in extra.items():
         out[k] = v
     return {k: jnp.asarray(v, jnp.float32) for k, v in out.items()}
-
-
-def fused_phase_proxy(sim) -> None:
-    """Run one instrumented per-round event so the trace carries a
-    per-phase device-time breakdown for the fused run (see module
-    docstring for the compile/measure double-run and skip conditions)."""
-    strat, tel = sim.strategy, sim.telemetry
-    event = strat.num_events(sim) - 1
-    if event < 0:
-        return
-    with tel.suppress():                      # compile pass
-        strat.run_event(sim, strat.init_state(sim), event,
-                        rng=np.random.default_rng(sim.fl.seed))
-    with tel.category("proxy"), \
-            tel.span("fused_phase_proxy", cat="proxy"):
-        strat.run_event(sim, strat.init_state(sim), event,
-                        rng=np.random.default_rng(sim.fl.seed))

@@ -22,8 +22,8 @@ emits:
                   scan) or the whole trace extent.
 
 Track assignment: category "phase" spans get one track per PHASE NAME
-(the per-phase view the issue asks for); every other category gets one
-track per category ("run", "proxy").
+(the per-phase view); every other category gets one track per category
+("run", "serve").
 """
 from __future__ import annotations
 
@@ -218,21 +218,18 @@ def validate_chrome_trace(doc: Any) -> List[str]:
 # -- result-document block ----------------------------------------------------
 
 def result_block(tel: Optional[Telemetry]) -> Dict[str, Any]:
-    """The `telemetry` block of result-JSON schema v2.3 (DESIGN.md §6):
-    per-phase totals, run-level spans, the fused per-phase proxy (when
-    one ran), counter totals, per-round series, dispatch-counter deltas,
-    and peak RSS."""
+    """The `telemetry` block of the result JSON (DESIGN.md §6, §13):
+    per-phase totals, run-level spans, counter totals (among them the
+    fused executor's `compile.requests` / `compile.cache_hits`),
+    per-round series, and peak RSS."""
     if tel is None or not tel.enabled:
         return {"enabled": False}
-    proxy = tel.summary("proxy")
     return {
         "enabled": True,
         "phases": tel.summary("phase"),
         "run": tel.summary("run"),
-        "fused_phase_proxy": proxy or None,
         "counters": {k: float(v) for k, v in sorted(tel.counters.items())},
         "series": {k: list(v) for k, v in sorted(tel.series.items())},
-        "dispatch": tel.dispatch_delta(),
         "peak_rss_mb": peak_rss_mb(),
     }
 
@@ -242,8 +239,9 @@ def result_block(tel: Optional[Telemetry]) -> Dict[str, Any]:
 @contextlib.contextmanager
 def profiler_trace(logdir: Optional[str] = None):
     """Opt-in `jax.profiler.trace` wrapper: XLA/TensorBoard profiles
-    land beside the host trace. No-op when `logdir` is falsy, so callers
-    can wrap unconditionally."""
+    land beside the host trace, and a simulation's recorded spans appear
+    in them as `prog.<name>` host events. No-op when `logdir` is falsy,
+    so callers can wrap unconditionally."""
     if not logdir:
         yield
         return

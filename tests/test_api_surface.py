@@ -54,7 +54,7 @@ def test_api_registry_contents():
 
 
 def test_api_schema_constants():
-    assert api.RESULT_SCHEMA_VERSION == 2.5
+    assert api.RESULT_SCHEMA_VERSION == 2.6
     assert api.STRATEGY_REGISTRY_VERSION == 1
     assert api.CODEC_REGISTRY_VERSION == 1
 

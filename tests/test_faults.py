@@ -364,7 +364,7 @@ def test_result_schema_v25_faults_block(small_ds):
         rounds=2, participation=1.0, fault_profile="dropout",
         churn_rate=0.5)
     res = scenarios.run_scenario(spec)
-    assert res["schema_version"] == scenarios.RESULT_SCHEMA_VERSION == 2.5
+    assert res["schema_version"] == scenarios.RESULT_SCHEMA_VERSION == 2.6
     assert res["faults"]["profile"] == "dropout"
     import json
     json.dumps(res)

@@ -1,9 +1,9 @@
-"""Observability subsystem (ISSUE 8, DESIGN.md §13).
+"""Observability subsystem (DESIGN.md §13).
 
-Four invariant families:
+Five invariant families:
 
-* the tracer itself — span recording, categories, suppress/override
-  scoping, dispatch-counter attribution, thread safety;
+* the tracer itself — span recording, categories, suppress scoping,
+  profiler annotations, thread safety;
 * the Chrome-trace exporter — every produced trace passes the format
   validator (matched B/E stacks, monotone per-track ts), and the
   validator actually rejects malformed documents;
@@ -12,19 +12,26 @@ Four invariant families:
   (the in-scan counters read existing scan values, never feed back);
 * the result-document contract — schema v2.3's `telemetry` block, the
   warmup/steady timing split, and `load_result` back-compat for
-  v1-v2.2 documents.
+  v1-v2.2 documents;
+* what the fused executor shows a profiler — its run spans as
+  `prog.<name>` host events, its round phases as named scopes in the
+  compiled program, and compile counters credited to one run.
 """
+import contextlib
+import glob
 import json
+import re
 import threading
 
+import jax
 import numpy as np
 import pytest
 
 from repro.core.fl_types import FLConfig
 from repro.core.simulation import FederatedSimulation
 from repro.data.synthetic import mnist_like
-from repro.obs import (Telemetry, chrome_trace, count, dispatch_snapshot,
-                       profiler_trace, result_block, validate_chrome_trace,
+from repro.obs import (Telemetry, chrome_trace, profiler_trace,
+                       result_block, validate_chrome_trace,
                        write_chrome_trace)
 
 
@@ -84,17 +91,42 @@ def test_suppress_mutes_everything():
     assert [s["name"] for s in tel.spans] == ["visible"]
 
 
-def test_category_override_retags_and_mutes_counters():
-    tel = Telemetry()
-    with tel.category("proxy"):
-        assert tel.sync_active
-        with tel.span("local_train", cat="phase"):
+class _Annotations:
+    """A stand-in for `jax.profiler.TraceAnnotation` that logs entries
+    and exits."""
+
+    def __init__(self):
+        self.log = []
+
+    @contextlib.contextmanager
+    def __call__(self, name):
+        self.log.append(("enter", name))
+        yield
+        self.log.append(("exit", name))
+
+
+def test_span_enters_prog_annotation():
+    ann = _Annotations()
+    tel = Telemetry(annotate=ann)
+    with tel.span("warmup", cat="run"):
+        with tel.span("lower", cat="run"):
             pass
-        tel.counter("c")               # muted: proxy is a measurement pass
-        tel.append_series("s", 1.0)    # muted
-    assert not tel.sync_active
-    assert tel.spans[0]["cat"] == "proxy"
-    assert not tel.counters and not tel.series
+    assert ann.log == [("enter", "prog.warmup"), ("enter", "prog.lower"),
+                       ("exit", "prog.lower"), ("exit", "prog.warmup")]
+    assert [s["name"] for s in tel.spans] == ["lower", "warmup"]
+
+
+@pytest.mark.parametrize("mute", ["suppressed", "disabled"])
+def test_muted_span_enters_no_annotation(mute):
+    ann = _Annotations()
+    tel = Telemetry(enabled=mute != "disabled", annotate=ann)
+    if mute == "suppressed":
+        with tel.suppress(), tel.span("hidden"):
+            pass
+    else:
+        with tel.span("hidden"):
+            pass
+    assert ann.log == [] and tel.spans == []
 
 
 def test_counters_and_series_accumulate():
@@ -122,14 +154,6 @@ def test_summary_groups_by_name_within_category():
     assert phases["eval"]["mean_s"] == pytest.approx(
         phases["eval"]["total_s"] / 3)
     assert set(tel.summary("run")) == {"classify"}
-
-
-def test_dispatch_delta_attributes_to_one_run():
-    count("test_obs.fake", 2)
-    tel = Telemetry()                   # snapshots AFTER the 2 above
-    count("test_obs.fake", 3)
-    assert dispatch_snapshot()["test_obs.fake"] >= 5
-    assert tel.dispatch_delta()["test_obs.fake"] == 3
 
 
 def test_tracer_is_thread_safe():
@@ -268,7 +292,7 @@ def test_fused_in_scan_counters_and_proxy(obs_ds):
     cfg = _cfg("fused", strategy="afl", attack="sign_flip",
                defense="median", rounds=3)
     sim = FederatedSimulation(cfg, obs_ds)
-    sim.run()
+    r = sim.run()
     tel = sim.telemetry
     # in-scan counters ride the scan outputs: one value per round, and
     # the attacker count is a constant the host also knows
@@ -276,12 +300,13 @@ def test_fused_in_scan_counters_and_proxy(obs_ds):
     assert tel.series["scan.attackers"] == [float(len(sim.attackers))] * 3
     assert len(tel.series["scan.model_delta_l2"]) == 3
     assert all(v > 0 for v in tel.series["scan.model_delta_l2"])
-    # run-level structure + the per-phase device-time proxy
+    # run-level structure; no per-round replay of the scan's phases
     run_spans = tel.summary("run")
-    for name in ("precompute", "warmup", "fused_scan", "classify"):
+    for name in ("construct", "precompute", "warmup", "lower", "compile",
+                 "fused_scan", "classify"):
         assert name in run_spans, name
-    proxy = tel.summary("proxy")
-    assert "local_train" in proxy and "aggregate" in proxy
+    assert tel.summary("phase") == {}
+    assert "fused_phase_proxy" not in r.extra["telemetry"]
     assert validate_chrome_trace(chrome_trace(tel)) == []
 
 
@@ -289,7 +314,7 @@ def test_fused_chunked_skips_proxy(obs_ds):
     cfg = _cfg("fused", strategy="afl", fused_chunk=4)
     sim = FederatedSimulation(cfg, obs_ds)
     sim.run()
-    assert sim.telemetry.summary("proxy") == {}
+    assert sim.telemetry.summary("phase") == {}
     assert len(sim.telemetry.series["scan.model_delta_l2"]) == 2
 
 
@@ -319,12 +344,113 @@ def test_async_counters_and_flow_trace(obs_ds):
     assert phs.count("s") == 1 and phs.count("f") == 1
 
 
-def test_dispatch_counters_per_engine(obs_ds):
-    sim = FederatedSimulation(_cfg("vectorized", strategy="afl"), obs_ds)
+@pytest.mark.parametrize("engine", ["vectorized", "fused"])
+def test_dispatch_counters_per_engine(obs_ds, engine):
+    """Compile counters: the fused executor counts the XLA compile of
+    its scan inside its `compile` span; the per-round engines compile
+    lazily at dispatch, outside any compile span, and count none."""
+    sim = FederatedSimulation(_cfg(engine, strategy="afl"), obs_ds)
+    r = sim.run()
+    counters = r.extra["telemetry"]["counters"]
+    assert "dispatch" not in r.extra["telemetry"]
+    if engine == "fused":
+        assert counters["compile.requests"] >= 1
+        assert counters.get("compile.cache_hits", 0) <= \
+            counters["compile.requests"]
+    else:
+        assert not any(k.startswith("compile.") for k in counters)
+
+
+def test_compile_counters_credit_each_run(obs_ds):
+    """Two simulations one after the other in one process: each counts
+    its own scan's compile on its own Telemetry."""
+    sims = [FederatedSimulation(_cfg("fused", strategy="hfl"), obs_ds)
+            for _ in range(2)]
+    for sim in sims:
+        sim.run()
+    for sim in sims:
+        assert sim.telemetry.counters["compile.requests"] >= 1
+
+
+def test_compile_cache_hit_credited_to_reading_run(obs_ds, tmp_path):
+    """With a persistent cache directory, the second identical run reads
+    its scan from the cache and counts the hit on its own Telemetry."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    before = {k: getattr(jax.config, k) for k in keys}
+    jax.config.update(keys[0], str(tmp_path / "cache"))
+    jax.config.update(keys[1], 0.0)
+    jax.config.update(keys[2], -1)
+    cc.reset_cache()
+    try:
+        tels = []
+        for _ in range(2):
+            sim = FederatedSimulation(_cfg("fused", strategy="afl"), obs_ds)
+            sim.run()
+            tels.append(sim.telemetry)
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+    assert tels[0].counters.get("compile.cache_hits", 0) == 0
+    assert tels[1].counters["compile.cache_hits"] >= 1
+
+
+def test_construct_lower_compile_spans_nest_in_warmup(obs_ds):
+    sim = FederatedSimulation(_cfg("fused", strategy="hfl"), obs_ds)
     sim.run()
-    delta = sim.telemetry.dispatch_delta()
-    assert delta.get("engine.train_dispatch", 0) >= 1
-    assert delta.get("kernel.fedavg_agg", 0) >= 1
+    spans = {s["name"]: s for s in sim.telemetry.spans
+             if s["cat"] == "run"}
+    assert {"construct", "lower", "compile"} <= set(
+        sim.telemetry.summary("run"))
+    warm = spans["warmup"]
+    w0, w1 = warm["ts_us"], warm["ts_us"] + warm["dur_us"]
+    for name in ("lower", "compile"):
+        s = spans[name]
+        assert w0 <= s["ts_us"] and s["ts_us"] + s["dur_us"] <= w1, name
+    assert spans["lower"]["ts_us"] + spans["lower"]["dur_us"] <= \
+        spans["compile"]["ts_us"]
+    # construction ends before the run starts
+    c = spans["construct"]
+    assert c["ts_us"] + c["dur_us"] <= spans["precompute"]["ts_us"]
+
+
+SCOPES = {"local_train", "local_eval", "aggregate", "eval"}
+
+
+@pytest.mark.parametrize("kw,want", [
+    (dict(strategy="hfl"), SCOPES),
+    (dict(strategy="afl", attack="sign_flip", defense="median"),
+     SCOPES | {"corrupt"}),
+    (dict(strategy="afl", codec="qsgd"), SCOPES | {"encode_decode"}),
+    (dict(strategy="cfl"), SCOPES),
+], ids=["hfl", "afl_signflip_median", "afl_qsgd", "cfl"])
+def test_fused_round_phases_are_named_scopes(obs_ds, kw, want):
+    """The fused round's phases carry the per-round driver's phase names
+    in the `op_name` metadata of the compiled scan."""
+    sim = FederatedSimulation(_cfg("fused", **kw), obs_ds)
+    sim.run()
+    text = sim.fused_program.as_text()
+    found = {part for name in re.findall(r'op_name="([^"]*)"', text)
+             for part in name.split("/")}
+    assert want <= found, sorted(want - found)
+
+
+def test_fused_spans_are_native_profiler_events(obs_ds, tmp_path):
+    """A jax.profiler trace of one small fused run holds the program's
+    own spans as `prog.<name>` host events, with nothing patched."""
+    from jax.profiler import ProfileData
+    with profiler_trace(str(tmp_path)):
+        FederatedSimulation(_cfg("fused", strategy="hfl"), obs_ds).run()
+    path = sorted(glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True))[-1]
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    for name in ("construct", "lower", "compile", "fused_scan"):
+        assert "prog." + name in names, name
 
 
 # ---------------------------------------------------------------------------
@@ -358,9 +484,10 @@ def test_run_scenario_trace_out_and_v23_schema(tmp_path):
     from repro.core import scenarios
     path = str(tmp_path / "t.json")
     doc = scenarios.run_scenario("iid-hfl-fused", trace_out=path)
-    assert doc["schema_version"] == scenarios.RESULT_SCHEMA_VERSION == 2.5
+    assert doc["schema_version"] == scenarios.RESULT_SCHEMA_VERSION == 2.6
     assert doc["telemetry"]["enabled"] is True
     assert "fused_scan" in doc["telemetry"]["run"]
+    assert not {"fused_phase_proxy", "dispatch"} & set(doc["telemetry"])
     assert doc["timing"]["warmup_time_s"] > 0.0
     assert doc["timing"]["steady_time_s"] == doc["timing"]["build_time_s"]
     with open(path) as f:
@@ -387,6 +514,15 @@ def test_load_result_backcompat_v22_and_older():
     up = load_result(v1)
     assert up["telemetry"] is None and up["attack"] is None
     assert up["strategy"]["plugin"] == "afl"
+
+
+def test_load_result_upgrades_v25():
+    from repro.core.scenarios import RESULT_SCHEMA_VERSION, load_result
+    tel = {"enabled": True, "run": {}, "counters": {}}
+    up = load_result({"schema_version": 2.5, "spec": {}, "telemetry": tel,
+                      "faults": None})
+    assert up["schema_version"] == RESULT_SCHEMA_VERSION
+    assert up["telemetry"] == tel and up["faults"] is None
 
 
 def test_profiler_trace_noop_and_real(tmp_path):

@@ -136,7 +136,7 @@ def test_result_schema_backward_compat_read():
     v1 = {"schema_version": 1, "scenario": "legacy",
           "metrics": {"test_accuracy": 0.9}, "async": None}
     doc = scenarios.load_result(v1)
-    assert doc["schema_version"] == scenarios.RESULT_SCHEMA_VERSION == 2.5
+    assert doc["schema_version"] == scenarios.RESULT_SCHEMA_VERSION == 2.6
     assert doc["attack"] is None
     assert doc["strategy"] == {"plugin": None, "registry_version": None}
     assert doc["communication"] is None
