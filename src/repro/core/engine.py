@@ -122,10 +122,10 @@ def _train_clients_impl(stacked_params, data, *, stacked_loss_fn, lr,
     ((C,) losses, (C,) accs); differentiating their SUM yields exactly the
     per-client gradients (clients are independent), so one scan step
     updates every client's SGD state at once. This is semantically
-    `vmap(scan(local_sgd))`, but the client axis runs through the stacked
-    forward path (`cnn_apply_stacked`) — a vmapped conv with per-client
-    kernels lowers to C sequential convolutions on CPU and its backward
-    pass dominates the round time ~40x.
+    `vmap(scan(local_sgd))`, with the client axis run through the
+    stacked forward path (`cnn_apply_stacked`), whose lowering
+    `models.cnn.stacked_lowering` picks from the backend and the stack's
+    size.
 
     `extra` (optional, traced) is passed through as the loss's third
     argument — a Strategy's per-client loss context with a leading client
@@ -159,6 +159,11 @@ def _train_clients_impl(stacked_params, data, *, stacked_loss_fn, lr,
     return stacked_params, losses.T, accs.T
 
 
+def train_stack_size(num_clients, chunk):
+    """Clients per training stack of `_train_clients_chunked_impl`."""
+    return chunk if 0 < chunk < num_clients else num_clients
+
+
 def _train_clients_chunked_impl(stacked_params, data, *, stacked_loss_fn,
                                 lr, momentum, extra=None, chunk):
     """`_train_clients_impl` one participant SUB-STACK at a time
@@ -172,7 +177,7 @@ def _train_clients_chunked_impl(stacked_params, data, *, stacked_loss_fn,
     differently, so the two agree to float rounding, not bitwise
     (tests/test_fused.py)."""
     C = jax.tree.leaves(stacked_params)[0].shape[0]
-    if chunk <= 0 or chunk >= C:
+    if train_stack_size(C, chunk) == C:
         return _train_clients_impl(
             stacked_params, data, stacked_loss_fn=stacked_loss_fn, lr=lr,
             momentum=momentum, extra=extra)

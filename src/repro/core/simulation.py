@@ -200,6 +200,17 @@ class FusedContext:
         c_loc = self.data_x.shape[0]
         return pids - jax.lax.axis_index(self.mesh_axis) * c_loc
 
+    def lowering(self, num_clients, chunk=0):
+        """`models.cnn.lowering_scope` for this shard's `num_clients`
+        stack, trained `chunk` clients at a time: the lowering that
+        `stacked_lowering` picks for the stack the single-device run
+        sees (all `num_clients` x shards of them on a mesh), so a
+        shard computes what one device computes."""
+        if self.mesh_axis is not None:
+            num_clients *= self.fl.mesh_devices
+        stack = engine_mod.train_stack_size(num_clients, chunk)
+        return cnn_mod.lowering_scope(cnn_mod.stacked_lowering(stack))
+
     def pmean(self, x):
         """Cross-shard mean of a per-shard scalar metric (identity
         off-mesh; shards are equal-size, so the mean of shard means is

@@ -389,20 +389,28 @@ class Strategy:
         per-round driver's phase span (`local_train`, `local_eval`,
         `corrupt`, `encode_decode`, `aggregate`, `eval`), so its ops
         carry the phase in their `op_name` metadata and a profiler trace
-        can attribute device time to it. Scopes are metadata only."""
+        can attribute device time to it. Scopes are metadata only.
+        Training and local evaluation trace inside `fx.lowering`, so a
+        mesh shard lowers the stacked CNN as the single-device run does;
+        counter `local_train.grouped_conv` records training's lowering."""
         fl = fx.fl
         bases = self.scan_bases(fx, carry, xs)
         pids = fx.local_pids(xs["pids"])
         spec = self.local_spec(fx.sim, None, None)
         extra = bases if spec.extra == "bases" else None
-        with jax.named_scope("local_train"):
+        n = jax.tree.leaves(bases)[0].shape[0]
+        with jax.named_scope("local_train"), \
+                fx.lowering(n, fl.fused_chunk) as lowered:
             batch = engine_mod.gather_batches(fx.data_x, fx.data_y,
                                               pids, xs["idx"])
             params, losses, _ = engine_mod._train_clients_chunked_impl(
                 bases, batch, stacked_loss_fn=spec.stacked_loss_fn,
                 lr=fl.lr, momentum=fl.momentum, extra=extra,
                 chunk=fl.fused_chunk)
-        with jax.named_scope("local_eval"):
+        if lowered:
+            fx.sim.telemetry.set_counter("local_train.grouped_conv",
+                                         float(lowered[0] == "grouped"))
+        with jax.named_scope("local_eval"), fx.lowering(n):
             accs = fx.local_accs(params, pids)
         with jax.named_scope("corrupt"):
             uploads = fx.corrupt(params, bases, xs)

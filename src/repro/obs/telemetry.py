@@ -121,6 +121,14 @@ class Telemetry:
         with self._lock:
             self.counters[name] = self.counters.get(name, 0.0) + float(value)
 
+    def set_counter(self, name: str, value: float) -> None:
+        """Set a named run-total counter, replacing any earlier value: a
+        fact of the run that each trace of the same code restates."""
+        if not self.enabled or self._suppress:
+            return
+        with self._lock:
+            self.counters[name] = float(value)
+
     def append_series(self, name: str, value: float) -> None:
         """Append one per-round value to a named series."""
         if not self.enabled or self._suppress:

@@ -81,6 +81,56 @@ def test_sharded_fused_matches_single_device(strategy, mode, attack, chunk):
 
 
 # ---------------------------------------------------------------------------
+# a shard lowers its slice of the stack as one device lowers the whole
+# ---------------------------------------------------------------------------
+
+LOWERING_SNIPPET = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    import sys, json
+    sys.path.insert(0, {src!r})
+    from repro.core.fl_types import FLConfig
+    from repro.core.simulation import FederatedSimulation
+    from repro.data.synthetic import mnist_like
+    from repro.models import cnn
+
+    # grouped for stacks of at most {grouped_max} clients
+    cnn.stacked_lowering = (
+        lambda n, backend=None: "grouped" if n <= {grouped_max} else "patch")
+    ds = mnist_like(seed=0, n_train=512, n_test=64)
+
+    def run(mesh):
+        fl = FLConfig(strategy="afl", num_clients=16, rounds=1,
+                      local_batch_size=16, seed=0, participation=1.0,
+                      engine="fused", mesh_devices=mesh)
+        sim = FederatedSimulation(fl, ds)
+        r = sim.run()
+        return {{"counter": r.extra["telemetry"]["counters"].get(
+                     "local_train.grouped_conv"),
+                 "grouped": "feature_group_count"
+                            in sim.fused_program.as_text()}}
+
+    print(json.dumps({{"single": run(0), "sharded": run(8)}}))
+""")
+
+
+@pytest.mark.parametrize("grouped_max,expect", [
+    (2, 0.0),       # a shard's 2 clients would pick grouped on their own
+    (16, 1.0),      # the whole stack of 16 picks grouped
+])
+def test_sharded_fused_lowers_as_single_device(grouped_max, expect):
+    """The lowering of each shard's 2-client slice is the one the single-
+    device run picks for all 16 clients, so the two compute alike."""
+    code = LOWERING_SNIPPET.format(src=SRC, grouped_max=grouped_max)
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=900)
+    assert out.returncode == 0, out.stderr[-3000:]
+    r = json.loads(out.stdout.strip().splitlines()[-1])
+    want = {"counter": expect, "grouped": expect == 1.0}
+    assert r == {"single": want, "sharded": want}, r
+
+
+# ---------------------------------------------------------------------------
 # HFL tier 1 is shard-local: zero collectives in its compiled HLO
 # ---------------------------------------------------------------------------
 

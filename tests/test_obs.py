@@ -361,6 +361,31 @@ def test_dispatch_counters_per_engine(obs_ds, engine):
         assert not any(k.startswith("compile.") for k in counters)
 
 
+@pytest.mark.parametrize("strategy,chunk,expect", [
+    ("afl", 0, 0.0),        # one stack of 8 clients: the patch lowering
+    ("afl", 2, 1.0),        # chunks of 2 clients: grouped
+    ("hfl", 0, 0.0),
+    ("hfl", 2, 1.0),
+    ("cfl", 0, None),       # sequential visits train no stack
+])
+def test_grouped_conv_counter(obs_ds, monkeypatch, strategy, chunk, expect):
+    """`local_train.grouped_conv` records the lowering that the fused
+    run's local training took, as `stacked_lowering` picks it (here
+    grouped for stacks of at most 2 clients): the compiled scan holds
+    grouped convolutions exactly when the counter reads 1."""
+    from repro.models import cnn as cnn_mod
+    monkeypatch.setattr(
+        cnn_mod, "stacked_lowering",
+        lambda n, backend=None: "grouped" if n <= 2 else "patch")
+    sim = FederatedSimulation(_cfg("fused", strategy=strategy,
+                                   fused_chunk=chunk), obs_ds)
+    r = sim.run()
+    assert r.extra["telemetry"]["counters"].get(
+        "local_train.grouped_conv") == expect
+    grouped = "feature_group_count" in sim.fused_program.as_text()
+    assert grouped == (expect == 1.0)
+
+
 def test_compile_counters_credit_each_run(obs_ds):
     """Two simulations one after the other in one process: each counts
     its own scan's compile on its own Telemetry."""

@@ -1,4 +1,5 @@
-"""The federated-learning Pallas kernels compile for a TPU v5e.
+"""The federated-learning Pallas kernels, and the stacked CNN's training
+step, compile for a TPU v5e.
 
 Interpret mode cannot see what the chip's compiler refuses: a tile that
 overflows VMEM, or a block that does not match XLA's layout. These tests
@@ -12,6 +13,7 @@ process may load the TPU library, and every test worker imports this
 file."""
 import os
 import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import comm_agg, fedavg_agg, gossip_mix, robust_agg
 from repro.launch import compile_cache
+from repro.models import cnn
 from repro.models.cnn import init_cnn
 
 
@@ -73,6 +76,53 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, C, N):
             for shape, dtype in arg_shapes(C, N)]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# an operand of the patch lowering: (..., kh*kw, cin) stacked patches or
+# their (C, B*H*W, kh*kw*cin) matrix, for cin of 1, 16 and 12
+PATCH_OPERAND = re.compile(r"\[[0-9,]*,9,(1|16|12)\]|,(9|144|108)\]")
+
+
+def _stacked_train_step(apply):
+    def step(params, images, labels):
+        def loss(p):
+            logp = jax.nn.log_softmax(apply(p, images))
+            nll = -jnp.take_along_axis(logp, labels[..., None], -1)
+            return nll.mean(axis=(1, 2)).sum()
+        return jax.value_and_grad(loss)(params)
+    return step
+
+
+@pytest.mark.parametrize("C,B", [
+    (10, 32),            # the paper's HFL federation, one stack
+    (128, 16),           # one chunk of the 1,024-client federation
+])
+@pytest.mark.parametrize("lowering", ["grouped", "patch"])
+def test_stacked_cnn_step_compiles_for_v5e(one_chip, lowering, C, B):
+    """The stacked value-and-grad step compiles for the chip under each
+    lowering. Where `stacked_lowering` picks the grouped lowering for the
+    chip, the step convolves with feature groups and builds no patch
+    tensor."""
+    apply = {"grouped": cnn.cnn_apply_grouped,
+             "patch": cnn.cnn_apply_patch}[lowering]
+    params = jax.eval_shape(
+        lambda k: jax.vmap(init_cnn)(jax.random.split(k, C)),
+        jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        params)
+    images = jax.ShapeDtypeStruct((C, B, 28, 28, 1), jnp.float32,
+                                  sharding=one_chip)
+    labels = jax.ShapeDtypeStruct((C, B), jnp.int32, sharding=one_chip)
+    lowered = jax.jit(_stacked_train_step(apply)).lower(params, images,
+                                                        labels)
+    hlo = lowered.compile().as_text()
+    if lowering == "patch":
+        assert PATCH_OPERAND.search(hlo)
+    elif cnn.stacked_lowering(C, "tpu") == "grouped":
+        assert f"feature_group_count = {C}" in lowered.as_text()
+        assert not PATCH_OPERAND.search(hlo), \
+            PATCH_OPERAND.search(hlo).group(0)
 
 
 @pytest.mark.parametrize("env", [None, "elsewhere"])
