@@ -36,18 +36,18 @@ from chip_bench.run import enable_compile_cache  # noqa: E402
 def readings(cell, seed):
     import jax.numpy as jnp
     from chip_bench import compare
-    from chip_bench import data as data_mod
     from chip_bench import run as run_mod
     from chip_bench.reference import federation as ref_mod
+    model = cell.family.reference_model()
     t0 = time.perf_counter()
-    dataset = data_mod.render(cell.config["data"], seed)
+    dataset = cell.family.render(cell.config["data"], seed)
     t1 = time.perf_counter()
     prog = run_mod.one_run(cell, dataset, seed, trace=False)
     gc.collect()
     t2 = time.perf_counter()
-    ref = ref_mod.run(cell.spec, dataset, seed)
+    ref = ref_mod.run(cell.spec, dataset, seed, model)
     t3 = time.perf_counter()
-    ctrl = ref_mod.run(cell.spec, dataset, seed, dtype=jnp.bfloat16)
+    ctrl = ref_mod.run(cell.spec, dataset, seed, model, dtype=jnp.bfloat16)
     t4 = time.perf_counter()
     ctrl_run = {"round_loss": ctrl["round_loss"],
                 "round_test_acc": ctrl["round_test_acc"],

@@ -6,6 +6,11 @@ so a cell is added by adding files and entries:
 
 * the configuration's `file` (`configs/<config>.json`): the model, the
   data set and its sizes, the federation's sizes and optimiser;
+* `families/<arch>.py`, named by the configuration's `model.arch`: the
+  model family — `render(data_spec, seed)`, `forward_flops(model_spec)`,
+  `reference_model()` (init, loss and accuracy of its plain reference)
+  and `program_kwargs(config)`; configurations share a family by naming
+  it;
 * `traffic/<traffic>.json`: strategy, defense, attack, mesh, epochs and
   rounds per run;
 * `limits/<cell>.json`: the limits of the comparison that decides
@@ -15,6 +20,7 @@ so a cell is added by adding files and entries:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import pathlib
@@ -46,16 +52,32 @@ class Cell:
     def fl_kwargs(self, seed: int) -> Dict[str, Any]:
         """Keyword arguments of the program's `FLConfig` for one run."""
         kw = dict(self.spec["federation"])
+        kw.update(self.family.program_kwargs(self.config))
         kw.update(engine="fused", seed=seed)
         return kw
 
+    @functools.cached_property
+    def family(self):
+        """The module `families/<model.arch>.py`, loaded once a cell."""
+        arch = self.config["model"]["arch"]
+        fdir = self.root / BENCH_DIR / "families"
+        path = fdir / f"{arch}.py"
+        if not path.is_file():
+            known = sorted(p.stem for p in fdir.glob("*.py"))
+            raise KeyError(f"no model family {arch!r} in {fdir} "
+                           f"(known: {', '.join(known)})")
+        return _load(path, "chip_bench_family_" + arch.replace(".", "_"))
+
     def reader(self, metric: str):
         path = self.root / BENCH_DIR / "metrics" / f"{metric}.py"
-        spec = importlib.util.spec_from_file_location(
-            "chip_bench_metric_" + metric.replace(".", "_"), path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return _load(path, "chip_bench_metric_" + metric.replace(".", "_")).read
+
+
+def _load(path: pathlib.Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def _applies(metric, cell_name):

@@ -1,12 +1,11 @@
 """The benchmark's cost model: the work a cell asks for, counted from its
-own sizes, whatever implements it.
+own sizes, whatever implements it. Nothing here knows a model: the FLOPs
+of one sample's forward pass come from the configuration's family
+(`families/<arch>.py`, `forward_flops`).
 
-* `cnn_forward_macs` — multiply-accumulates of one image's forward pass
-  through the paper CNN, from the layer shapes (convolutions and the
-  dense head; activations and pooling are not counted). Backward is
+* `run_work` — client-samples trained and samples evaluated in one whole
+  federation run of a cell, and the model FLOPs of both. Backward is
   counted as twice the forward, so one trained sample costs 3x forward.
-* `run_work` — client-samples trained and images evaluated in one whole
-  federation run of a cell, and the model FLOPs of both.
 * `fedavg_bytes` / `median_bytes` — compulsory HBM bytes of one
   aggregation over a (C, N) float32 stack: read the stack once, read the
   C weights (FedAvg), write the N-vector.
@@ -20,38 +19,6 @@ import pathlib
 
 PEAKS_FILE = pathlib.Path(__file__).with_name("peaks.json")
 F32 = 4
-
-
-def cnn_forward_macs(image=(28, 28, 1), filters=(16, 12, 10), kernel=3,
-                     pool=2, classes=10):
-    """Forward MACs of the paper CNN: SAME 3x3 convs with a 2x2 max-pool
-    after each but the last, then a dense layer to `classes`."""
-    h, w, cin = image
-    macs = 0
-    for i, cout in enumerate(filters):
-        macs += h * w * cout * kernel * kernel * cin
-        cin = cout
-        if i < len(filters) - 1:
-            h, w = h // pool, w // pool
-    return macs + h * w * cin * classes
-
-
-def cnn_params(image=(28, 28, 1), filters=(16, 12, 10), kernel=3, pool=2,
-               classes=10):
-    """Parameter count N of the paper CNN (the aggregation row width)."""
-    h, w, cin = image
-    n = 0
-    for i, cout in enumerate(filters):
-        n += kernel * kernel * cin * cout + cout
-        cin = cout
-        if i < len(filters) - 1:
-            h, w = h // pool, w // pool
-    return n + h * w * cin * classes + classes
-
-
-def model_macs(model):
-    return cnn_forward_macs(tuple(model["image"]), tuple(model["filters"]),
-                            model["kernel"], model["pool"], model["classes"])
 
 
 def participants(fed):
@@ -68,9 +35,10 @@ def shard_sizes(n_train, C):
     return [base + 1] * extra + [base] * (C - extra)
 
 
-def run_work(spec):
+def run_work(spec, forward_flops):
     """Work of one whole federation run of a cell spec (the merged
-    config + traffic dict of `cells.Cell.spec`)."""
+    config + traffic dict of `cells.Cell.spec`), whose model's forward
+    pass costs `forward_flops` a sample."""
     fed, data = spec["federation"], spec["data"]
     C, B = fed["num_clients"], fed["local_batch_size"]
     sizes = shard_sizes(data["n_train"], C)
@@ -82,10 +50,9 @@ def run_work(spec):
     # in-scan evaluation per round: every participant's local model on
     # its eval shard, and the round model on the whole test set
     eval_images = R * (k * n_eval + data["n_test"])
-    fwd = model_macs(spec["model"])
     return {"client_samples": samples, "eval_images": eval_images,
-            "train_flops": 6 * fwd * samples,
-            "eval_flops": 2 * fwd * eval_images}
+            "train_flops": 3 * forward_flops * samples,
+            "eval_flops": forward_flops * eval_images}
 
 
 def fedavg_bytes(C, N):

@@ -1,6 +1,7 @@
 """Host seconds of `FederatedSimulation.run()` outside its `fused_scan`
-span, mean over the window's runs: precompute, lowering the scan, the
-per-phase proxy, classification."""
+span, mean over the window's runs: precompute, lowering and compiling
+the scan (or reading it from the compilation cache), the predict
+warm-ups, classification."""
 
 
 def read(ctx):

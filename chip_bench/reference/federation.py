@@ -1,23 +1,24 @@
-"""Plain reference of the federations the cells run.
+"""Plain reference of the federations the cells run, for any model.
 
 Independent of the program: straight `jax.numpy` / `lax`, one model per
-client (`vmap` over clients), each convolution written out tap by tap,
-float32 at HIGHEST matmul precision, no kernels, no stacking tricks. It follows
-the paper CNN (arXiv:2512.10987 §2.4, Fig. 7) and the federation as the
+client (`vmap` over clients), float32 at HIGHEST matmul precision, no
+kernels, no stacking tricks. The model comes from the configuration's
+family (`families/<arch>.py`, `reference_model()`) as three functions:
+`init(seed, model_spec)`, the program's initial weights for the seed;
+`loss(params, x, y, precision)`, the mean loss of a batch; and
+`accuracy(params, x, y, precision)`, the share of a batch's label
+entries predicted right. Everything else is the federation as the
 cell's configuration and traffic files state it:
 
-* data: the set the benchmark rendered, split IID — a seeded permutation
+* data: the set the family rendered, split IID — a seeded permutation
   of the train indices cut into C contiguous parts (`np.array_split`),
   each part sorted;
-* initial weights from `jax.random.PRNGKey(seed)`: four split keys, the
-  conv kernels N(0, 1)/sqrt(fan_in) in HWIO, the dense kernel
-  N(0, 1)/sqrt(490), zero biases;
 * per round, from `np.random.default_rng(seed)`: the participants (AFL:
   `rng.choice` without replacement, sorted; CFL: a permutation that is
   the visit order; HFL: every client), then per participant and epoch
   one permutation of its shard, cut to whole batches;
-* local SGD with heavy-ball momentum (fresh per round), the mean
-  cross-entropy of each batch;
+* local SGD with heavy-ball momentum (fresh per round) on the family's
+  loss of each batch;
 * Byzantine clients (AFL): `attack_fraction` of the federation drawn
   from `np.random.default_rng([seed, 0x5EEDA77C])`; sign-flip uploads
   `base - scale * (local - base)`;
@@ -37,7 +38,6 @@ control.
 """
 from __future__ import annotations
 
-import math
 from functools import partial
 
 import jax
@@ -48,74 +48,15 @@ from jax import lax
 ATTACK_SALT = 0x5EEDA77C
 
 
-# -- model ------------------------------------------------------------------
-
-def init_params(seed, filters=(16, 12, 10), classes=10, image=(28, 28, 1)):
-    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
-    p, cin = {}, image[2]
-    for i, cout in enumerate(filters):
-        k = jax.random.normal(ks[i], (3, 3, cin, cout)) / math.sqrt(9 * cin)
-        p[f"conv{i + 1}"] = {"kernel": k, "bias": jnp.zeros((cout,))}
-        cin = cout
-    feat = (image[0] // 4) * (image[1] // 4) * cin
-    p["head"] = {"kernel": (jax.random.normal(ks[3], (feat, classes))
-                            / math.sqrt(feat)),
-                 "bias": jnp.zeros((classes,))}
-    return p
-
-
-def conv_same(h, k, prec):
-    """Stride-1 SAME convolution, NHWC by HWIO, written out as the sum over
-    the kernel's taps of a shifted input times that tap's (cin, cout)
-    matrix. Under `vmap` over clients each tap is one batched matmul,
-    where a per-client `lax.conv` would become a grouped convolution."""
-    kh, kw = k.shape[0], k.shape[1]
-    H, W = h.shape[1], h.shape[2]
-    hp = jnp.pad(h, ((0, 0), (kh // 2, kh // 2), (kw // 2, kw // 2), (0, 0)))
-    out = 0.0
-    for i in range(kh):
-        for j in range(kw):
-            out = out + jnp.einsum("bhwc,co->bhwo",
-                                   hp[:, i:i + H, j:j + W, :], k[i, j],
-                                   precision=prec)
-    return out
-
-
-def forward(p, x, prec):
-    """x (B, 28, 28, 1) -> logits (B, 10)."""
-    def conv(q, h):
-        return jax.nn.relu(conv_same(h, q["kernel"], prec) + q["bias"])
-
-    def pool(h):
-        return lax.reduce_window(h, np.array(-np.inf, h.dtype), lax.max,
-                                 (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
-
-    h = pool(conv(p["conv1"], x))
-    h = pool(conv(p["conv2"], h))
-    h = conv(p["conv3"], h)
-    h = h.reshape(h.shape[0], -1)
-    return jnp.dot(h, p["head"]["kernel"], precision=prec) + p["head"]["bias"]
-
-
-def loss_fn(p, x, y, prec):
-    logp = jax.nn.log_softmax(forward(p, x, prec))
-    return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=1))
-
-
-def accuracy(p, x, y, prec):
-    return jnp.mean((jnp.argmax(forward(p, x, prec), -1) == y)
-                    .astype(jnp.float32))
-
-
-def local_sgd(p, xb, yb, lr, mom, prec):
+def local_sgd(p, xb, yb, lr, mom, prec, loss):
     """One client's local training over batches (T, B, ...): returns the
     trained model and the loss of each batch before its step."""
     def step(carry, batch):
         q, mu = carry
-        loss, g = jax.value_and_grad(loss_fn)(q, batch[0], batch[1], prec)
+        value, g = jax.value_and_grad(loss)(q, batch[0], batch[1], prec)
         mu = jax.tree.map(lambda m, gi: mom * m + gi, mu, g)
         q = jax.tree.map(lambda a, m: a - lr * m, q, mu)
-        return (q, mu), loss
+        return (q, mu), value
 
     mu0 = jax.tree.map(jnp.zeros_like, p)
     (p, _), losses = lax.scan(step, (p, mu0), (xb, yb))
@@ -124,8 +65,8 @@ def local_sgd(p, xb, yb, lr, mom, prec):
 
 # -- jitted round pieces ----------------------------------------------------
 
-@partial(jax.jit, static_argnames=("prec", "block"))
-def train_clients(bases, x_dev, y_dev, gidx, lr, mom, *, prec, block):
+@partial(jax.jit, static_argnames=("loss", "prec", "block"))
+def train_clients(bases, x_dev, y_dev, gidx, lr, mom, *, loss, prec, block):
     """Every participant from its own base: bases (k, ...) stacked,
     gidx (k, T, B) indices into the device train set. Runs `block`
     clients at a time so the reference fits beside nothing else."""
@@ -135,7 +76,7 @@ def train_clients(bases, x_dev, y_dev, gidx, lr, mom, *, prec, block):
     def one_block(args):
         b, gi = args
         return jax.vmap(lambda q, g: local_sgd(q, x_dev[g], y_dev[g], lr,
-                                               mom, prec))(b, gi)
+                                               mom, prec, loss))(b, gi)
 
     params, losses = lax.map(one_block,
                              (jax.tree.map(split, bases), split(gidx)))
@@ -143,24 +84,29 @@ def train_clients(bases, x_dev, y_dev, gidx, lr, mom, *, prec, block):
     return jax.tree.map(merge, params), merge(losses)
 
 
-@partial(jax.jit, static_argnames=("prec",))
-def local_accuracy(params, x_dev, y_dev, eidx, *, prec):
+@partial(jax.jit, static_argnames=("accuracy", "prec"))
+def local_accuracy(params, x_dev, y_dev, eidx, *, accuracy, prec):
     """Each trained local model on its own eval shard: eidx (k, n)."""
     return jax.vmap(lambda q, e: accuracy(q, x_dev[e], y_dev[e], prec))(
         params, eidx)
 
 
-@partial(jax.jit, static_argnames=("prec", "block"))
-def test_accuracy(p, x, y, *, prec, block):
+@partial(jax.jit, static_argnames=("accuracy", "prec", "block"))
+def test_accuracy(p, x, y, *, accuracy, prec, block):
+    """Share of the test labels predicted right, `block` samples at a
+    time: each block's count of hits is its accuracy times its number of
+    label entries, rounded to the whole number it is."""
+    def hits(xb, yb):
+        return jnp.round(accuracy(p, xb, yb, prec) * yb.size).astype(
+            jnp.int32)
+
     n = x.shape[0] // block * block
     xs = x[:n].reshape((-1, block) + x.shape[1:])
-    ys = y[:n].reshape(-1, block)
-    hits = jnp.sum(lax.map(
-        lambda a: jnp.sum(jnp.argmax(forward(p, a[0], prec), -1) == a[1]),
-        (xs, ys)))
+    ys = y[:n].reshape((-1, block) + y.shape[1:])
+    total = jnp.sum(lax.map(lambda a: hits(*a), (xs, ys)))
     if n < x.shape[0]:
-        hits += jnp.sum(jnp.argmax(forward(p, x[n:], prec), -1) == y[n:])
-    return hits / x.shape[0]
+        total += hits(x[n:], y[n:])
+    return total / y.size
 
 
 def weighted_mean(stack, w):
@@ -173,13 +119,15 @@ def coordinate_median(stack):
                         stack)
 
 
-@partial(jax.jit, static_argnames=("prec",))
-def cfl_round(model, x_dev, y_dev, gidx, eidx, lr, mom, alpha, *, prec):
+@partial(jax.jit, static_argnames=("loss", "accuracy", "prec"))
+def cfl_round(model, x_dev, y_dev, gidx, eidx, lr, mom, alpha, *, loss,
+              accuracy, prec):
     """One continual pass: visits in order, each trains from the carried
     model and merges into it."""
     def visit(m, args):
         gi, ei = args
-        local, losses = local_sgd(m, x_dev[gi], y_dev[gi], lr, mom, prec)
+        local, losses = local_sgd(m, x_dev[gi], y_dev[gi], lr, mom, prec,
+                                  loss)
         acc = accuracy(local, x_dev[ei], y_dev[ei], prec)
         m = jax.tree.map(lambda a, b: (1 - alpha) * a + alpha * b, m, local)
         return m, (losses, acc)
@@ -241,11 +189,13 @@ DEFAULTS = {"defense": "none", "attack": "none", "afl_mode": "fedavg",
             "codec": "none", "fault_profile": "none"}
 
 
-def run(spec, dataset, seed, *, dtype=jnp.float32, block=128):
-    """Run the cell's federation. Returns numpy results: round_loss,
+def run(spec, dataset, seed, model, *, dtype=jnp.float32, block=128):
+    """Run the cell's federation with `model`, the family's
+    `(init, loss, accuracy)`. Returns numpy results: round_loss,
     round_train_acc, round_test_acc (R,), init and final global params
-    ({"conv1/kernel": array, ...})."""
-    fed, model = spec["federation"], spec["model"]
+    ({"<layer>/<leaf>": array, ...})."""
+    fed = spec["federation"]
+    init_fn, loss, accuracy = model
     for key, allowed in SUPPORTED.items():
         val = fed.get(key, DEFAULTS.get(key))
         if val not in allowed or (fed["strategy"] == "cfl"
@@ -267,8 +217,7 @@ def run(spec, dataset, seed, *, dtype=jnp.float32, block=128):
     x_test = jnp.asarray(xte, dtype)
     y_test = jnp.asarray(yte)
     cast = lambda t: jax.tree.map(lambda a: a.astype(dtype), t)  # noqa
-    init = init_params(seed, tuple(model["filters"]), model["classes"],
-                       tuple(model["image"]))
+    init = init_fn(seed, spec["model"])
     glob = cast(init)
     lr = jnp.asarray(fed["lr"], dtype)
     mom = jnp.asarray(fed["momentum"], dtype)
@@ -286,7 +235,8 @@ def run(spec, dataset, seed, *, dtype=jnp.float32, block=128):
         if strategy == "cfl":
             glob, (ls, accs) = cfl_round(
                 glob, x_dev, y_dev, gi, ei, lr, mom,
-                jnp.asarray(fed.get("merge_alpha", 0.5), dtype), prec=prec)
+                jnp.asarray(fed.get("merge_alpha", 0.5), dtype), loss=loss,
+                accuracy=accuracy, prec=prec)
         else:
             if strategy == "hfl":
                 per = C // G
@@ -296,8 +246,9 @@ def run(spec, dataset, seed, *, dtype=jnp.float32, block=128):
                 bases = jax.tree.map(lambda a: jnp.stack([a] * k), glob)
             blk = block if k % block == 0 else k
             params, ls = train_clients(bases, x_dev, y_dev, gi, lr, mom,
-                                       prec=prec, block=blk)
-            accs = local_accuracy(params, x_dev, y_dev, ei, prec=prec)
+                                       loss=loss, prec=prec, block=blk)
+            accs = local_accuracy(params, x_dev, y_dev, ei,
+                                  accuracy=accuracy, prec=prec)
             w = weights[jnp.asarray(pids)]
             if strategy == "hfl":
                 tier1 = [weighted_mean(
@@ -325,7 +276,8 @@ def run(spec, dataset, seed, *, dtype=jnp.float32, block=128):
         losses.append(float(jnp.mean(ls[:, -nb:].astype(jnp.float32))))
         train_accs.append(float(jnp.mean(accs)))
         test_accs.append(float(test_accuracy(
-            glob, x_test, y_test, prec=prec, block=min(2000, len(yte)))))
+            glob, x_test, y_test, accuracy=accuracy, prec=prec,
+            block=min(2000, len(yte)))))
     return {"round_loss": np.asarray(losses),
             "round_train_acc": np.asarray(train_accs),
             "round_test_acc": np.asarray(test_accs),
@@ -333,7 +285,7 @@ def run(spec, dataset, seed, *, dtype=jnp.float32, block=128):
 
 
 def flat(tree):
-    """{"conv1/kernel": float32 numpy array, ...}."""
+    """{"<layer>/<leaf>": float32 numpy array, ...}: leaf paths joined by "/"."""
     out = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
         name = "/".join(str(getattr(k, "key", k)) for k in path)

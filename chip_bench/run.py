@@ -3,15 +3,17 @@
     python3 chip_bench/run.py --workload mnist_c10.hfl --seed 7 \
         --seconds 20 --trace 0
 
-Set-up renders the cell's data set from `--seed` and makes one whole
+Set-up renders the cell's data set from `--seed` (by the configuration's
+model family, `families/<arch>.py`) and makes one whole
 federation run of the cell (compiling it, or reading it from JAX's
 persistent compilation cache). The measured window then makes whole
 runs back to back through the program's public entry,
 `repro.api.FederatedSimulation(FLConfig(engine="fused", ...), data)
 .run()`, each a new simulation on the same config and seed, until
 `--seconds` have passed; the run in flight is finished. Afterwards the
-plain reference (`reference/federation.py`) runs the same federation
-once, and every run of the window is compared with it (`compare.py`).
+plain reference (`reference/federation.py`, with the family's reference
+model) runs the same federation once, and every run of the window is
+compared with it (`compare.py`).
 
 The last line of standard output is one JSON object: `correct`,
 `attempted` (runs in the window), `failed` (runs that broke a limit),
@@ -125,8 +127,8 @@ def _annotate_spans(tel):
 
 def one_run(cell, dataset, seed, trace):
     """One whole federation run through the public entry. Returns the
-    host timings, the program's run-level spans and the results that
-    the comparison reads (as numpy)."""
+    host timings, the program's run-level spans and counters, and the
+    results that the comparison reads (as numpy)."""
     import jax
     from repro import api
     from chip_bench.reference.federation import flat
@@ -144,10 +146,12 @@ def one_run(cell, dataset, seed, trace):
     t2 = time.perf_counter()
     with ann("bench.collect"):
         final = sim.strategy.round_model(sim.final_state)
+        tel = res.extra["telemetry"]
         out = {
             "construct_s": t1 - t0, "run_s": t2 - t1,
             "spans": {k: v["total_s"] for k, v in
-                      res.extra["telemetry"].get("run", {}).items()},
+                      tel.get("run", {}).items()},
+            "counters": dict(tel.get("counters", {})),
             "round_loss": list(res.round_train_loss),
             "round_test_acc": list(res.round_test_acc),
             "final": flat(final),
@@ -174,12 +178,11 @@ def run_cell(cell, seed, seconds, trace, *, t_start=T_START):
     object. Checks for no chip: `main` does that."""
     import jax
     from chip_bench import compare, costs
-    from chip_bench import data as data_mod
     from chip_bench.reference import federation as ref_mod
 
     counter = CompileCounter.get()
     prog_seed = seed & 0xFFFFFFFF
-    dataset = data_mod.render(cell.config["data"], prog_seed)
+    dataset = cell.family.render(cell.config["data"], prog_seed)
     one_run(cell, dataset, prog_seed, trace=False)        # warm-up
     setup_s = time.perf_counter() - t_start
 
@@ -212,14 +215,16 @@ def run_cell(cell, seed, seconds, trace, *, t_start=T_START):
     # the reference, once the window has closed and the program's state
     # is freed
     gc.collect()
-    ref = ref_mod.run(cell.spec, dataset, prog_seed)
+    ref = ref_mod.run(cell.spec, dataset, prog_seed,
+                      cell.family.reference_model())
     run_gaps = [compare.gaps(r, ref) for r in runs]
     checks, failed = compare.judge(run_gaps, cell.limits)
     for n in compare.NUMBERS:
         print(f"reading {n}: {max(g[n] for g in run_gaps)!r}",
               file=sys.stderr)
 
-    work = costs.run_work(cell.spec)
+    work = costs.run_work(cell.spec,
+                          cell.family.forward_flops(cell.config["model"]))
     result = {"correct": failed == 0 and bool(runs),
               "attempted": len(runs), "failed": failed}
     if trace:

@@ -14,9 +14,9 @@ for _p in (ROOT / "src", ROOT):
     if str(_p) not in sys.path:
         sys.path.insert(0, str(_p))
 
-SINGLE = ("mnist_c10.hfl", "fmnist_c1024.afl_median", "mnist_c10.cfl")
-# a four-chip cell is not in the benchmark yet; `add_mesh_cell` adds it to
-# a tiny root as files alone, from the committed `afl_mesh4` traffic mix
+SINGLE = ("mnist_c10.hfl", "fmnist_c1024.afl_median")
+# the four-chip cell: its client axis sharded over four devices, which a
+# CPU test gets only in a child process (`test_chip_bench_mesh_faults.py`)
 MESH = "fmnist_c1024.afl_mesh4"
 
 
@@ -37,15 +37,3 @@ def make_root(dst: pathlib.Path) -> pathlib.Path:
         p.write_text(json.dumps(tr))
     (dst / "BENCHMARK.json").write_text(json.dumps(bench))
     return dst
-
-
-def add_mesh_cell(root: pathlib.Path, limit: float = 0.05) -> None:
-    """The 1,024-client AFL sharded over four chips, as a cell of the tiny
-    root: a workload entry and a limits file."""
-    bench = json.loads((root / "BENCHMARK.json").read_text())
-    bench["workloads"].append({"name": MESH, "config": "cnn.fmnist.c1024",
-                               "traffic": "afl_mesh4", "chips": 4,
-                               "why": "the client axis over four chips"})
-    (root / "BENCHMARK.json").write_text(json.dumps(bench))
-    (root / "chip_bench" / "limits" / f"{MESH}.json").write_text(
-        json.dumps({"limits": {"param_gap": {"limit": limit}}}))

@@ -1,19 +1,27 @@
-"""The benchmark's cost model and peaks table."""
-import chip_bench_tiny  # noqa: F401  (puts the checkout on sys.path)
+"""The benchmark's cost model, the paper CNN family's counts and the
+peaks table."""
+import chip_bench_tiny
 import pytest
 
-from chip_bench import costs
+from chip_bench import cells, costs
+
+CELL = cells.load(chip_bench_tiny.SINGLE[0])
+CNN = CELL.family
 
 
 def test_paper_cnn_forward_macs():
     # conv1 28*28*16*9*1 + conv2 14*14*12*9*16 + conv3 7*7*10*9*12
     # + dense 490*10
-    assert costs.cnn_forward_macs() == 509_404
+    assert CNN.forward_macs() == 509_404
     assert (112_896 + 338_688 + 52_920 + 4_900) == 509_404
+    model = CELL.config["model"]
+    assert CNN.forward_flops(model) == 2 * 509_404
+    assert model["forward_macs_per_image"] == 509_404
 
 
 def test_paper_cnn_params():
-    assert costs.cnn_params() == 7_900
+    assert CNN.param_count() == 7_900
+    assert CELL.config["model"]["params"] == 7_900
 
 
 def test_fedavg_bytes_at_ten_clients():
@@ -50,6 +58,6 @@ def test_client_samples_per_run(fed, samples):
                       "kernel": 3, "pool": 2, "classes": 10},
             "data": {"n_train": 60_000, "n_test": 10_000},
             "federation": fed}
-    w = costs.run_work(spec)
+    w = costs.run_work(spec, CNN.forward_flops(spec["model"]))
     assert w["client_samples"] == samples
     assert w["train_flops"] == 6 * 509_404 * samples

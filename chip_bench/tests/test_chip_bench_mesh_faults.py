@@ -1,8 +1,8 @@
-"""A cell whose client axis is sharded over four chips, added to a tiny
-root as files alone, in a child process with four host devices (set
-before JAX starts there): the harness reports `correct` true for the
-sound program and false for each fault planted under it, the exchange
-between chips left out among them."""
+"""The cell whose client axis is sharded over four chips, at the tiny
+root's size and held to its committed limits, in a child process with
+four host devices (set before JAX starts there): the harness reports
+`correct` true for the sound program and false for each fault planted
+under it, the exchange between chips left out among them."""
 import os
 import subprocess
 import sys
@@ -15,9 +15,7 @@ SEED = 2**31 + 91
 
 @pytest.fixture(scope="module")
 def root(tmp_path_factory):
-    root = chip_bench_tiny.make_root(tmp_path_factory.mktemp("tiny"))
-    chip_bench_tiny.add_mesh_cell(root)
-    return root
+    return chip_bench_tiny.make_root(tmp_path_factory.mktemp("tiny"))
 
 
 def test_mesh_cell_faults_are_not_correct(root):
