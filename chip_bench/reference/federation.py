@@ -32,9 +32,27 @@ cell's configuration and traffic files state it:
   first min(512, smallest shard) samples of its own shard, and the
   accuracy of the round model on the whole test set.
 
-`dtype=jnp.bfloat16` computes everything (data, weights, momentum,
-aggregation) in bfloat16 at the default precision: the lower-precision
-control.
+`dtype=jnp.bfloat16` computes everything (floating data, weights,
+momentum, aggregation) in bfloat16 at the default precision: the
+lower-precision control. Only floating arrays take `dtype`: integer
+inputs (token ids, labels) and integer leaves reach the family's `loss`
+and `accuracy` as they were rendered, in the reference and the control
+alike, since bfloat16 would round every id above 256.
+
+A round's AFL or HFL participants train by one of two paths, chosen
+from bytes (`stacked`): side by side (`train_clients`, a `vmap` over
+k copies of their bases) where k x (one client's bytes, from the
+family's `init` by `jax.eval_shape`) x 4 (base, trained model, momentum,
+gradient) fits in half of the device's `memory_stats()["bytes_limit"]`,
+or where the backend reports no limit (the CPU); else streamed
+(`stream_client`): one participant at a time from the round's model, in
+participant order, its local accuracy taken as it finishes and its
+upload (sign-flipped if it attacks) folded into a running weighted sum
+in `dtype` that the total weight divides at the round's end, so that
+the device holds the round's model, the sum and one client's training
+state. The streamed path has no HFL (G group models and G sums) and no
+median defense (it needs the whole stack): both raise
+`NotImplementedError`. CFL visits one client at a time on either path.
 """
 from __future__ import annotations
 
@@ -109,6 +127,22 @@ def test_accuracy(p, x, y, *, accuracy, prec, block):
     return total / y.size
 
 
+@partial(jax.jit, static_argnames=("loss", "accuracy", "prec"),
+         donate_argnums=(1,))
+def stream_client(base, total, x_dev, y_dev, gi, ei, w, flip, scale, lr, mom,
+                  *, loss, accuracy, prec):
+    """One participant of a streamed round, trained from the round's
+    model `base` over gi (T, B): its local accuracy on its eval rows ei,
+    and `total` + w x its upload (`base - scale * (local - base)` where
+    `flip`). Returns (total, the loss of each batch, the accuracy)."""
+    local, losses = local_sgd(base, x_dev[gi], y_dev[gi], lr, mom, prec, loss)
+    acc = accuracy(local, x_dev[ei], y_dev[ei], prec)
+    total = jax.tree.map(
+        lambda t, l, b: t + w * jnp.where(flip, b - scale * (l - b), l),
+        total, local, base)
+    return total, losses, acc
+
+
 def weighted_mean(stack, w):
     w = w / jnp.sum(w)
     return jax.tree.map(lambda a: jnp.tensordot(w, a, axes=1), stack)
@@ -153,6 +187,39 @@ def attackers(C, fraction, seed):
     return mask
 
 
+def round_size(fed):
+    """Participants a round trains: a share of the clients (AFL) or every
+    client (HFL, and CFL one after another)."""
+    C = fed["num_clients"]
+    if fed["strategy"] == "afl":
+        return max(1, int(round(fed.get("participation", 0.5) * C)))
+    return C
+
+
+def client_bytes(init_fn, seed, model_spec):
+    """Bytes of one client's parameters as the family's `init` makes
+    them, from their shapes alone (`jax.eval_shape`: nothing allocated)."""
+    shapes = jax.eval_shape(lambda: init_fn(seed, model_spec))
+    return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(shapes))
+
+
+def stacked(spec, nbytes, bytes_limit):
+    """Whether a round's participants train side by side: k of them, each
+    `nbytes` x 4 (base, trained model, momentum, gradient), in half of the
+    device's `bytes_limit`; with no limit known (the CPU), they do."""
+    return (bytes_limit is None
+            or 4 * round_size(spec["federation"]) * nbytes <= bytes_limit / 2)
+
+
+def floating(a):
+    return jnp.issubdtype(a.dtype, jnp.floating)
+
+
+def cast(tree, dtype):
+    """Floating leaves in `dtype`; integer leaves as they are."""
+    return jax.tree.map(lambda a: a.astype(dtype) if floating(a) else a, tree)
+
+
 def schedule(fed, parts, seed):
     """Per round: (participants in training order, (k, T, B) global train
     indices of their batches)."""
@@ -163,8 +230,8 @@ def schedule(fed, parts, seed):
     out = []
     for _ in range(fed["rounds"]):
         if fed["strategy"] == "afl":
-            k = max(1, int(round(fed.get("participation", 0.5) * C)))
-            pids = np.sort(rng.choice(C, size=k, replace=False))
+            pids = np.sort(rng.choice(C, size=round_size(fed),
+                                      replace=False))
         elif fed["strategy"] == "cfl":
             pids = rng.permutation(C)
         else:
@@ -189,11 +256,13 @@ DEFAULTS = {"defense": "none", "attack": "none", "afl_mode": "fedavg",
             "codec": "none", "fault_profile": "none"}
 
 
-def run(spec, dataset, seed, model, *, dtype=jnp.float32, block=128):
+def run(spec, dataset, seed, model, *, dtype=jnp.float32, block=128,
+        stream=False):
     """Run the cell's federation with `model`, the family's
     `(init, loss, accuracy)`. Returns numpy results: round_loss,
     round_train_acc, round_test_acc (R,), init and final global params
-    ({"<layer>/<leaf>": array, ...})."""
+    ({"<layer>/<leaf>": array, ...}). `stream=True` takes the streamed
+    path whatever the bytes (for tests)."""
     fed = spec["federation"]
     init_fn, loss, accuracy = model
     for key, allowed in SUPPORTED.items():
@@ -203,6 +272,19 @@ def run(spec, dataset, seed, model, *, dtype=jnp.float32, block=128):
             raise NotImplementedError(
                 f"the reference has no {key}={val!r} for strategy "
                 f"{fed['strategy']!r}")
+    strategy = fed["strategy"]
+    defense = fed.get("defense", "none")
+    nbytes = client_bytes(init_fn, seed, spec["model"])
+    limit = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+    stream = strategy != "cfl" and (stream or not stacked(spec, nbytes,
+                                                          limit))
+    if stream and (strategy == "hfl" or defense == "median"):
+        what = "strategy 'hfl'" if strategy == "hfl" else \
+            "defense 'median'"
+        raise NotImplementedError(
+            f"the streamed reference (clients of {nbytes:,} bytes, "
+            f"{round_size(fed)} a round, device limit {limit}) has no "
+            f"{what}")
     prec = (lax.Precision.HIGHEST if dtype == jnp.float32
             else lax.Precision.DEFAULT)
     xtr, ytr = dataset["train"]
@@ -212,31 +294,47 @@ def run(spec, dataset, seed, model, *, dtype=jnp.float32, block=128):
     n_eval = min(512, min(len(p) for p in parts))
     eval_rows = np.stack([p[:n_eval] for p in parts])
     rounds, nb = schedule(fed, parts, seed)
-    x_dev = jnp.asarray(xtr, dtype)
+    x_dev = jnp.asarray(xtr, dtype if floating(xtr) else None)
     y_dev = jnp.asarray(ytr)
-    x_test = jnp.asarray(xte, dtype)
+    x_test = jnp.asarray(xte, dtype if floating(xte) else None)
     y_test = jnp.asarray(yte)
-    cast = lambda t: jax.tree.map(lambda a: a.astype(dtype), t)  # noqa
     init = init_fn(seed, spec["model"])
-    glob = cast(init)
+    glob = cast(init, dtype)
+    init = flat(init)
     lr = jnp.asarray(fed["lr"], dtype)
     mom = jnp.asarray(fed["momentum"], dtype)
     weights = jnp.asarray([len(p) for p in parts], dtype)
     mask = attackers(C, fed.get("attack_fraction", 0.25), seed) \
         if fed.get("attack", "none") != "none" else np.zeros(C, bool)
-    strategy = fed["strategy"]
+    scale = jnp.asarray(fed.get("attack_scale", 1.0), dtype)
     G = fed.get("num_groups", 2)
-    groups = jax.tree.map(lambda a: jnp.stack([a] * G), glob)
+    if strategy == "hfl":
+        groups = jax.tree.map(lambda a: jnp.stack([a] * G), glob)
     losses, train_accs, test_accs = [], [], []
     for ev, (pids, gidx) in enumerate(rounds):
         k = len(pids)
         gi = jnp.asarray(gidx)
         ei = jnp.asarray(eval_rows[pids])
+        w = weights[jnp.asarray(pids)]
         if strategy == "cfl":
             glob, (ls, accs) = cfl_round(
                 glob, x_dev, y_dev, gi, ei, lr, mom,
                 jnp.asarray(fed.get("merge_alpha", 0.5), dtype), loss=loss,
                 accuracy=accuracy, prec=prec)
+        elif stream:
+            total = jax.tree.map(jnp.zeros_like, glob)
+            ls, accs = [], []
+            for i, c in enumerate(pids):
+                total, li, ai = stream_client(
+                    glob, total, x_dev, y_dev, gi[i], ei[i], w[i],
+                    bool(mask[c]), scale, lr, mom, loss=loss,
+                    accuracy=accuracy, prec=prec)
+                ls.append(li)
+                accs.append(ai)
+            total_w = jnp.sum(w)
+            glob = jax.tree.map(lambda t: t / total_w, total)
+            del total
+            ls, accs = jnp.stack(ls), jnp.stack(accs)
         else:
             if strategy == "hfl":
                 per = C // G
@@ -249,7 +347,6 @@ def run(spec, dataset, seed, model, *, dtype=jnp.float32, block=128):
                                        loss=loss, prec=prec, block=blk)
             accs = local_accuracy(params, x_dev, y_dev, ei,
                                   accuracy=accuracy, prec=prec)
-            w = weights[jnp.asarray(pids)]
             if strategy == "hfl":
                 tier1 = [weighted_mean(
                     jax.tree.map(lambda a: a[g * per:(g + 1) * per], params),
@@ -264,12 +361,11 @@ def run(spec, dataset, seed, model, *, dtype=jnp.float32, block=128):
             else:
                 flags = jnp.asarray(mask[pids])
                 if fed.get("attack", "none") == "sign_flip":
-                    s = jnp.asarray(fed.get("attack_scale", 1.0), dtype)
                     params = jax.tree.map(
                         lambda l, b: jnp.where(
                             flags.reshape((k,) + (1,) * (l.ndim - 1)),
-                            b - s * (l - b), l), params, bases)
-                if fed.get("defense", "none") == "median":
+                            b - scale * (l - b), l), params, bases)
+                if defense == "median":
                     glob = coordinate_median(params)
                 else:
                     glob = weighted_mean(params, w)
@@ -281,7 +377,7 @@ def run(spec, dataset, seed, model, *, dtype=jnp.float32, block=128):
     return {"round_loss": np.asarray(losses),
             "round_train_acc": np.asarray(train_accs),
             "round_test_acc": np.asarray(test_accs),
-            "init": flat(init), "final": flat(glob)}
+            "init": init, "final": flat(glob)}
 
 
 def flat(tree):
