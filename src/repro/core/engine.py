@@ -426,10 +426,13 @@ class VectorizedClientEngine:
                 f"statistical on this partition",
                 self.dropped_samples), stacklevel=2)
         self.n_eval = min(512, min(sizes))
-        self.eval_x = jnp.stack(
-            [jnp.asarray(x[: self.n_eval]) for x, _ in client_data])
-        self.eval_y = jnp.stack(
-            [jnp.asarray(y[: self.n_eval]) for _, y in client_data])
+        # stacked on the host, one device put each: a device copy per
+        # client first would hold C small, tile-padded arrays beside the
+        # stack (on a v5e about 1 GB at 1,024 clients of 58 samples)
+        self.eval_x = jnp.asarray(
+            np.stack([x[: self.n_eval] for x, _ in client_data]))
+        self.eval_y = jnp.asarray(
+            np.stack([y[: self.n_eval] for _, y in client_data]))
 
     # -- batching -----------------------------------------------------------
     def batch_indices(self, rng: np.random.Generator,

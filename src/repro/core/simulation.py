@@ -46,10 +46,12 @@ merging fuse.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
+import threading
 import warnings
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -155,14 +157,63 @@ def _batched(x, y, batch_size, rng):
             "label": jnp.asarray(y[sel].reshape(nb, batch_size))}
 
 
+@dataclasses.dataclass(frozen=True)
+class FusedStatic:
+    """Every Python value the fused scan's trace reads, and so the key of
+    its compiled program (DESIGN.md §10): value-compared and hashable.
+    Arrays reach the scan as arguments (`_fused_consts`, carry, `xs`),
+    and JAX keys their shapes and shardings itself, so two simulations
+    with equal `FusedStatic`s run one program. It holds no simulation
+    and no device array. The run seed is not in it (`fl` has it zeroed):
+    a seed sweep of one shape shares one program.
+
+    strategy         — a registered Strategy class (rebuilt in-trace from
+                       `fl`), or a plugin instance, keyed by identity.
+    fl               — the run's `FLConfig` with `seed=0`.
+    track, nb        — the strategy's `track_curves`; whole batches per
+                       client epoch.
+    loss_fn, apply_fn, stacked_apply_fn — the engine's model functions.
+    lowerings        — ((stack size, stacked-CNN lowering), ...) for the
+                       stacks the round trains and evaluates, as
+                       `models.cnn.stacked_lowering` picks them on the host.
+    attack_any       — some client of the federation is an attacker.
+    carry_sharding   — mesh path only: sorted (carry key, "client" |
+                       "replicated") pairs (`Strategy.scan_carry_sharding`).
+    """
+    strategy: Any
+    fl: FLConfig
+    track: bool
+    nb: int
+    loss_fn: Any
+    apply_fn: Any
+    stacked_apply_fn: Any
+    lowerings: Tuple[Tuple[int, str], ...]
+    attack_any: bool
+    carry_sharding: Optional[Tuple[Tuple[str, str], ...]] = None
+
+    def make_strategy(self):
+        """The strategy the trace runs."""
+        if isinstance(self.strategy, type):
+            return self.strategy(self.fl)
+        return self.strategy
+
+    def lowering(self, stack):
+        """The stacked-CNN lowering of a `stack`-client stack: the key's,
+        or for a stack the default round does not train (a plugin's
+        own `scan_round`), `stacked_lowering`'s."""
+        return dict(self.lowerings).get(stack) \
+            or cnn_mod.stacked_lowering(stack)
+
+
 class FusedContext:
     """What one fused-scan round sees (DESIGN.md §10): the device-resident
     run state — stacked federation dataset, per-client eval shards,
     client weights, test split — plus the static config. Built INSIDE the
-    jitted scan from explicitly-passed arrays (`_fused_consts`), so the
-    data arrives as program inputs rather than baked-in constants.
-    `Strategy.scan_round`/`scan_bases`/`scan_aggregate` receive this as
-    their first argument.
+    jitted scan from the program key (`FusedStatic`) and the explicitly
+    passed arrays (`_fused_consts`), so the data arrives as program
+    inputs rather than baked-in constants and the program outlives the
+    simulation that built it. `Strategy.scan_round`/`scan_bases`/
+    `scan_aggregate` receive this as their first argument.
 
     Under the mesh-sharded path (DESIGN.md §11) the scan body runs
     inside shard_map and every client-axis array here is the shard's
@@ -172,9 +223,13 @@ class FusedContext:
     `pmean` averages per-round scalars across shards. All three are
     identity when `mesh_axis` is None, so strategy code is written once."""
 
-    def __init__(self, sim, consts, *, mesh_axis=None):
-        self.sim, self.fl, self.eng = sim, sim.fl, sim.vec
-        self.nb = sim.vec.nb
+    def __init__(self, static, consts):
+        fl = static.fl
+        self.static, self.fl, self.nb = static, fl, static.nb
+        self.loss_fn, self.apply_fn = static.loss_fn, static.apply_fn
+        self.stacked_apply_fn = static.stacked_apply_fn
+        self.codec = (codecs_mod.get_codec(fl.codec)(fl)
+                      if fl.codec != "none" else None)
         self.data_x = consts["data_x"]
         self.data_y = consts["data_y"]
         self.eval_x = consts["eval_x"]
@@ -182,13 +237,16 @@ class FusedContext:
         self.weights = consts["weights"]          # (C,) float32 [local]
         self.x_test = consts["x_test"]
         self.y_test = consts["y_test"]
-        self.track = sim.strategy.track_curves
-        self.mesh_axis = mesh_axis
+        self.track = static.track
+        self.mesh_axis = "data" if fl.mesh_devices > 1 else None
         # per-client codec state for the CURRENT scan step (error-
         # feedback residuals): the executor threads it through the scan
         # carry and parks it here across the strategy's scan_round call
         # (None when the codec is stateless or inactive)
         self._codec_carry = None
+        # set by `Strategy.scan_round` when local training traced a
+        # stacked CNN call (the `local_train.grouped_conv` counter)
+        self.trained_stacked = False
 
     def local_pids(self, pids):
         """Absolute participant ids -> rows of this shard's sub-stack
@@ -202,14 +260,14 @@ class FusedContext:
 
     def lowering(self, num_clients, chunk=0):
         """`models.cnn.lowering_scope` for this shard's `num_clients`
-        stack, trained `chunk` clients at a time: the lowering that
-        `stacked_lowering` picks for the stack the single-device run
-        sees (all `num_clients` x shards of them on a mesh), so a
+        stack, trained `chunk` clients at a time: the lowering the host
+        picked (`FusedStatic.lowerings`) for the stack the single-device
+        run sees (all `num_clients` x shards of them on a mesh), so a
         shard computes what one device computes."""
         if self.mesh_axis is not None:
             num_clients *= self.fl.mesh_devices
         stack = engine_mod.train_stack_size(num_clients, chunk)
-        return cnn_mod.lowering_scope(cnn_mod.stacked_lowering(stack))
+        return cnn_mod.lowering_scope(self.static.lowering(stack))
 
     def pmean(self, x):
         """Cross-shard mean of a per-shard scalar metric (identity
@@ -220,13 +278,13 @@ class FusedContext:
         return jax.lax.pmean(x, self.mesh_axis)
 
     def defense_kwargs(self, event_size=None):
-        return self.sim.defense_kwargs(event_size)
+        return _defense_kwargs(self.fl, event_size)
 
     def local_accs(self, params, pids):
         """The paper's post-training local-shard accuracy, in-trace —
         the same math as `VectorizedClientEngine.local_accs`."""
         preds = jnp.argmax(
-            self.eng.stacked_apply_fn(params, self.eval_x[pids]), axis=-1)
+            self.stacked_apply_fn(params, self.eval_x[pids]), axis=-1)
         return jnp.mean((preds == self.eval_y[pids]).astype(jnp.float32),
                         axis=1)
 
@@ -235,8 +293,7 @@ class FusedContext:
         (`attacks.corrupt_stacked`), flags/keys hoisted into scan inputs
         — honest rows pass through bitwise unchanged (DESIGN.md §8)."""
         fl = self.fl
-        if fl.attack in ("none", "label_flip") \
-                or not self.sim.attack_mask.any():
+        if fl.attack in ("none", "label_flip") or not self.static.attack_any:
             return uploads
         return attacks.corrupt_stacked(uploads, bases, xs["flags"],
                                        xs["keys"], kind=fl.attack,
@@ -249,7 +306,7 @@ class FusedContext:
         `xs['ckeys']`; error-feedback rows ride the scan carry via
         `_codec_carry`. Identity when codec='none' (bitwise degeneracy:
         the traced program is unchanged)."""
-        codec = self.sim.codec
+        codec = self.codec
         if codec is None:
             return uploads
         mat = ops.stacked_ravel(uploads)
@@ -276,8 +333,125 @@ class FusedContext:
         return jnp.mean((preds == self.y_test).astype(jnp.float32))
 
 
+def _defense_kwargs(fl, event_size=None):
+    """kwargs for the defended aggregation operators, with the Byzantine
+    allowance resolved for an event of `event_size` clients."""
+    return {"defense": fl.defense,
+            "f": fl.resolved_defense_f(event_size),
+            "tau": fl.clip_tau}
+
+
+def _mesh_specs(carry_sharding, carry, xs, consts):
+    """PartitionSpecs of the mesh path's three scan inputs (DESIGN.md
+    §11): carry subtrees by `carry_sharding` ("client": leading dim over
+    "data"); `run_fused`'s four client-axis per-round inputs shard dim
+    1, strategy extra xs (per-round scalars) replicate; every const but
+    the test split shards its client axis."""
+    from jax.sharding import PartitionSpec as P
+    from repro.sharding import specs as specs_mod
+    carry_specs = {
+        k: (specs_mod.client_stack_specs(carry[k])
+            if carry_sharding[k] == "client"
+            else specs_mod.replicated_specs(carry[k]))
+        for k in carry}
+    xs_specs = {k: (P(None, "data")
+                    if k in ("pids", "idx", "flags", "keys", "fault_alive")
+                    else P())
+                for k in xs}
+    consts_specs = {k: (P() if k in ("x_test", "y_test") else P("data"))
+                    for k in consts}
+    return carry_specs, xs_specs, consts_specs
+
+
+class _FusedProgram:
+    """The one jitted fused scan of a `FusedStatic`. JAX's own trace,
+    lowering and executable caches, keyed by this object's function and
+    by the arguments' avals and shardings, do the rest: a second
+    simulation of the key lowers and compiles from them in
+    milliseconds. `jax.clear_caches()` empties those caches, and the
+    next use traces and compiles anew. `traces` counts traces (a
+    simulation that adds none reused the program); `trained_stacked`
+    says whether the last trace's local training ran a stacked CNN."""
+
+    def __init__(self, static):
+        self.static = static
+        self.traces = 0
+        self.trained_stacked = False
+        self.jitted = jax.jit(self._run, donate_argnums=(0,))
+
+    def _run(self, carry, xs, consts):
+        self.traces += 1
+        st = self.static
+        if st.fl.mesh_devices <= 1:
+            return self._scan(carry, xs, consts)
+        from jax.sharding import PartitionSpec as P
+        from repro.launch import mesh as mesh_launch
+        specs = _mesh_specs(dict(st.carry_sharding), carry, xs, consts)
+        # replication checking is off: local client ids come from
+        # `axis_index` arithmetic, which check_vma cannot type through
+        # `lax.scan` (the §11 parity tests pin correctness instead)
+        return jax.shard_map(
+            self._scan, mesh=mesh_launch.make_client_mesh(
+                st.fl.mesh_devices),
+            in_specs=specs, out_specs=(specs[0], (P(), P(), P())),
+            check_vma=False)(carry, xs, consts)
+
+    def _scan(self, carry, xs, consts):
+        st = self.static
+        fl, strat = st.fl, st.make_strategy()
+        fx = FusedContext(st, consts)
+        stateful = fx.codec is not None and fx.codec.stateful
+        # in-scan per-round counters (DESIGN.md §13) ride the stacked
+        # outputs next to the metric curves, one transfer at run end;
+        # so does the per-round global model when serving (DESIGN.md
+        # §14: the rounds live inside one scan, so `run_fused` replays
+        # the publishes after it). Both are off under the mesh: the
+        # shard_map out_specs describe the bare metric triple.
+        scan_tel = fl.telemetry and fx.mesh_axis is None
+
+        def body(c, x):
+            if stateful:
+                sc, cc = c
+                fx._codec_carry = cc
+                sc_new, out = strat.scan_round(fx, sc, x)
+                c_new = (sc_new, fx._codec_carry)
+            else:
+                sc = c
+                sc_new, out = strat.scan_round(fx, sc, x)
+                c_new = sc_new
+            if fl.serve:
+                out = (out, strat.round_model(sc_new))
+            if scan_tel:
+                out = (out, obs_collectors.round_counters(
+                    strat, fx, sc, sc_new, x))
+            return c_new, out
+
+        out = jax.lax.scan(body, carry, xs)
+        self.trained_stacked = fx.trained_stacked
+        return out
+
+
+# the fused programs of the last few keys, most recent last
+_PROGRAMS: "collections.OrderedDict[FusedStatic, _FusedProgram]" = \
+    collections.OrderedDict()
+_PROGRAMS_MAX = 8
+_programs_lock = threading.Lock()
+
+
+def _fused_program(static):
+    """The memoised `_FusedProgram` of `static` (a bounded LRU)."""
+    with _programs_lock:
+        prog = _PROGRAMS.pop(static, None) or _FusedProgram(static)
+        _PROGRAMS[static] = prog
+        while len(_PROGRAMS) > _PROGRAMS_MAX:
+            _PROGRAMS.popitem(last=False)
+        return prog
+
+
 def _fused_consts(sim):
-    """The device arrays a fused run passes into its compiled scan."""
+    """The device arrays a fused run passes into its compiled scan. The
+    test images are the classification phase's cached full-split head,
+    so a centralized strategy holds them on the device once."""
     eng = sim.vec
     data_x, data_y = eng.stacked_dataset()
     x_test, y_test = sim.dataset["test"]
@@ -285,7 +459,8 @@ def _fused_consts(sim):
             "eval_x": eng.eval_x, "eval_y": eng.eval_y,
             "weights": jnp.asarray(np.asarray(sim.weights, np.float64),
                                    jnp.float32),
-            "x_test": jnp.asarray(x_test), "y_test": jnp.asarray(y_test)}
+            "x_test": sim._test_head_dev(len(x_test)),
+            "y_test": jnp.asarray(y_test)}
 
 
 class FederatedSimulation:
@@ -315,7 +490,8 @@ class FederatedSimulation:
         self.init_params = (model_init or cnn_mod.init_cnn)(key)
         # resolve the strategy plugin: an instance is used as-is (plugin
         # escape hatch), a name resolves through the registry
-        if isinstance(strategy, strat_mod.Strategy):
+        self._strategy_plugin = isinstance(strategy, strat_mod.Strategy)
+        if self._strategy_plugin:
             self.strategy = strategy
         else:
             try:
@@ -485,10 +661,7 @@ class FederatedSimulation:
     def defense_kwargs(self, event_size=None) -> Dict[str, Any]:
         """kwargs for the defended aggregation operators, with the
         Byzantine allowance resolved for this event's client count."""
-        fl = self.fl
-        return {"defense": fl.defense,
-                "f": fl.resolved_defense_f(event_size),
-                "tau": fl.clip_tau}
+        return _defense_kwargs(self.fl, event_size)
 
     def _build_bases_stacked(self, plan):
         """One FRESH stacked round-start-bases tree (uncached): from the
@@ -751,12 +924,14 @@ class FederatedSimulation:
             robust.clip_update(self.init_params, self.init_params,
                                fl.clip_tau)
 
-    def _warmup_predicts(self):
+    def _warmup_predicts(self, full=None):
         """Compile the classification/eval `_predict` shapes (shared by
-        both engines)."""
+        both engines). `full`: the whole test split on the device, where
+        the caller holds it already."""
         x_test = self.dataset["test"][0]
         _predict(self.init_params, jnp.asarray(x_test[:500]))
-        _predict(self.init_params, jnp.asarray(x_test))             # full
+        _predict(self.init_params,                                   # full
+                 jnp.asarray(x_test) if full is None else full)
         shard = -(-len(x_test) // self.fl.num_clients)
         _predict(self.init_params, jnp.asarray(x_test[:shard]))     # shard
         # stragglers of the batched _eval: the final partial batch
@@ -829,8 +1004,10 @@ class FederatedSimulation:
         per (client, epoch) (`batch_indices`) — and hoists the results
         into the scan's per-round inputs. Warmup = AOT-compiling the
         scan (DESIGN.md §3: the build timer measures ONE steady-state
-        execution of the compiled run). The scan carry is donated, so
-        round t+1's state reuses round t's buffers."""
+        execution of the compiled run), or finding the program an
+        earlier simulation of the same key (`FusedStatic`: any seed,
+        any data of the same shapes) built. The scan carry is donated,
+        so round t+1's state reuses round t's buffers."""
         fl, strat = self.fl, self.strategy
         if self.vec is None:
             raise ValueError(
@@ -914,76 +1091,54 @@ class FederatedSimulation:
             # the pre-codec one
             carry0 = (carry0, codec_state)
 
-        mesh_axis = "data" if fl.mesh_devices > 1 else None
-        # in-scan per-round counters (DESIGN.md §13): ride the scan's
-        # stacked outputs next to the metric curves, one transfer at run
-        # end. Off under the mesh — `_mesh_wrap`'s out_specs describe
-        # the bare metric triple (per-shard counter semantics are
-        # future work).
-        scan_tel = tel.enabled and mesh_axis is None
-        # serving (DESIGN.md §14): the fused engine cannot publish at
-        # round boundaries — the rounds live inside one scan — so the
-        # per-round GLOBAL model rides the stacked outputs (same
-        # discipline as the in-scan counters above) and the publishes
-        # are REPLAYED in round order after the scan; the virtual-clock
-        # serving block comes out byte-identical to the per-round
-        # drivers'. serve+mesh is rejected by FLConfig (out_specs).
-        serve_stack = fl.serve
-
-        def _run(carry, xs, consts):
-            fx = FusedContext(self, consts, mesh_axis=mesh_axis)
-
-            def body(c, x):
-                if codec_state is not None:
-                    sc, cc = c
-                    fx._codec_carry = cc
-                    sc_new, out = strat.scan_round(fx, sc, x)
-                    c_new = (sc_new, fx._codec_carry)
-                else:
-                    sc = c
-                    sc_new, out = strat.scan_round(fx, sc, x)
-                    c_new = sc_new
-                if serve_stack:
-                    out = (out, strat.round_model(sc_new))
-                if scan_tel:
-                    out = (out, obs_collectors.round_counters(
-                        strat, fx, sc, sc_new, x))
-                return c_new, out
-
-            return jax.lax.scan(body, carry, xs)
-
-        run_fn = _run
-        if mesh_axis is not None:
-            run_fn, carry0, xs, consts = self._mesh_wrap(
-                _run, carry0, xs, consts, pids)
+        if fl.mesh_devices > 1:
+            self._mesh_validate(pids)
+        static = self._fused_static(k)
+        program = _fused_program(static)
+        if fl.mesh_devices > 1:
+            carry0, xs, consts = self._mesh_place(static, carry0, xs,
+                                                  consts)
 
         # warmup = lower + compile the scan once (AOT, so the donated
         # carry is not consumed) + the classification-phase predict
         # shapes; lowering and compiling are spans of their own, which
-        # count the run's compile requests and persistent-cache hits
+        # count the run's compile requests and persistent-cache hits.
+        # A program this process already built for the same key
+        # (`FusedStatic`) comes back from JAX's caches: the two spans
+        # then time that lookup, and `fused.program_reused` reads 1.
         warmup_timer = Timer()
         with tel.span("warmup", cat="run"), warmup_timer:
+            traces = program.traces
+            requests = tel.counters.get("compile.requests", 0.0)
             with obs_collectors.compile_span(tel, "lower"):
-                lowered = jax.jit(run_fn, donate_argnums=(0,)).lower(
-                    carry0, xs, consts)
+                lowered = program.jitted.lower(carry0, xs, consts)
             with obs_collectors.compile_span(tel, "compile"):
                 compiled = lowered.compile()
+            tel.set_counter("fused.program_reused", float(
+                program.traces == traces
+                and tel.counters.get("compile.requests", 0.0) == requests))
             with tel.suppress():
-                self._warmup_predicts()
+                n_test = len(self.dataset["test"][0])
+                self._warmup_predicts(full=self._test_head_dev(n_test))
+        if program.trained_stacked:
+            # the lowering local training took: 1 grouped, 0 patch
+            train_stack = engine_mod.train_stack_size(k, fl.fused_chunk)
+            tel.set_counter("local_train.grouped_conv",
+                            float(static.lowering(train_stack) == "grouped"))
 
         build_timer = Timer()
         with build_timer, tel.span("fused_scan", cat="run", rounds=R):
             carry, outs = compiled(carry0, xs, consts)
             jax.block_until_ready((carry, outs))
-        if scan_tel:
+        if fl.telemetry and fl.mesh_devices <= 1:
             outs, scan_counters = outs
         else:
             scan_counters = {}
         round_models = None
-        if serve_stack:
+        if fl.serve:
             outs, round_models = outs
         acc_r, loss_r, tacc_r = outs
-        if mesh_axis is not None:
+        if fl.mesh_devices > 1:
             # the classification phase mixes this state with
             # single-device test shards — re-home the final carry so
             # those computations colocate (untimed, like the
@@ -1048,9 +1203,31 @@ class FederatedSimulation:
                                          build_timer,
                                          warmup_timer=warmup_timer)
 
-    def _mesh_wrap(self, run, carry0, xs, consts, pids):
+    def _fused_static(self, k):
+        """This run's `FusedStatic`: the program key, from host values
+        alone. `k` clients train each round; the stacked-CNN lowering of
+        each stack the default round traces (training in chunks of
+        `fused_chunk`, local evaluation whole) is decided here."""
+        fl, strat, eng = self.fl, self.strategy, self.vec
+        stacks = {engine_mod.train_stack_size(k, fl.fused_chunk), k}
+        carry_sharding = None
+        if fl.mesh_devices > 1:
+            carry_sharding = tuple(sorted(
+                strat.scan_carry_sharding(self).items()))
+        return FusedStatic(
+            strategy=(strat if self._strategy_plugin else type(strat)),
+            fl=dataclasses.replace(fl, seed=0),
+            track=strat.track_curves, nb=eng.nb, loss_fn=eng.loss_fn,
+            apply_fn=eng.apply_fn, stacked_apply_fn=eng.stacked_apply_fn,
+            lowerings=tuple(sorted(
+                (n, cnn_mod.stacked_lowering(n)) for n in stacks)),
+            attack_any=bool(self.attack_mask.any()),
+            carry_sharding=carry_sharding)
+
+    def _mesh_validate(self, pids):
         """DESIGN.md §11: the fused scan under `shard_map`, the stacked
-        CLIENT axis partitioned over a 1-D ("data",) mesh.
+        CLIENT axis partitioned over a 1-D ("data",) mesh
+        (`_FusedProgram._run` wraps the scan, `_mesh_specs` says how).
 
         Local training / corruption / eval are embarrassingly parallel
         per shard; each strategy's `scan_aggregate` lowers its event to
@@ -1059,17 +1236,7 @@ class FederatedSimulation:
         partitioned POSITIONALLY, so every round must train every client
         (full participation), shards must be equal (C % ndev == 0), and
         in-scan defenses are off (they rank across the whole federation;
-        scan-level robust aggregation on the mesh is future work). Inputs
-        are device_put onto their NamedShardings up front: the AOT call
-        then needs no resharding, and the federation stack never
-        materializes on a single device.
-
-        Returns (wrapped_fn, carry0, xs, consts) with the three input
-        trees resharded."""
-        from jax.sharding import NamedSharding
-        from jax.sharding import PartitionSpec as P
-        from repro.launch import mesh as mesh_launch
-        from repro.sharding import specs as specs_mod
+        scan-level robust aggregation on the mesh is future work)."""
         fl, strat = self.fl, self.strategy
         ndev, C = fl.mesh_devices, fl.num_clients
         if not strat.supports_mesh:
@@ -1100,27 +1267,21 @@ class FederatedSimulation:
                 "mesh path needs full participation (participation=1.0): "
                 "the client axis is sharded positionally, so every round "
                 "must train clients 0..C-1 in id order")
-        mesh = mesh_launch.make_client_mesh(ndev)
-        sharding = strat.scan_carry_sharding(self)
+
+    def _mesh_place(self, static, carry0, xs, consts):
+        """The mesh path's scan inputs, device_put onto their
+        NamedShardings up front: the AOT call then needs no resharding,
+        and the federation stack never materializes on a single
+        device."""
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
+        from repro.launch import mesh as mesh_launch
+        mesh = mesh_launch.make_client_mesh(self.fl.mesh_devices)
+        sharding = dict(static.carry_sharding)
         if set(sharding) != set(carry0):
             raise ValueError(
                 f"scan_carry_sharding keys {sorted(sharding)} do not "
                 f"match the scan carry {sorted(carry0)}")
-        carry_specs = {
-            k: (specs_mod.client_stack_specs(carry0[k])
-                if sharding[k] == "client"
-                else specs_mod.replicated_specs(carry0[k]))
-            for k in carry0}
-        # hoisted per-round inputs: the driver's four client-axis
-        # tensors shard dim 1; strategy extra xs are per-round scalars
-        # (replicated) by the supports_mesh contract
-        xs_specs = {k: (P(None, "data")
-                        if k in ("pids", "idx", "flags", "keys",
-                                 "fault_alive") else P())
-                    for k in xs}
-        consts_specs = {k: (P() if k in ("x_test", "y_test")
-                            else P("data")) for k in consts}
-        out_specs = (carry_specs, (P(), P(), P()))
 
         def _put(tree, specs):
             return jax.tree.map(
@@ -1128,14 +1289,9 @@ class FederatedSimulation:
                 specs, tree,
                 is_leaf=lambda x: isinstance(x, P))
 
-        # replication checking is off: local client ids come from
-        # `axis_index` arithmetic, which check_vma cannot type through
-        # `lax.scan` (the §11 parity tests pin correctness instead)
-        wrapped = jax.shard_map(
-            run, mesh=mesh, in_specs=(carry_specs, xs_specs, consts_specs),
-            out_specs=out_specs, check_vma=False)
-        return (wrapped, _put(carry0, carry_specs), _put(xs, xs_specs),
-                _put(consts, consts_specs))
+        return tuple(_put(tree, specs) for tree, specs in zip(
+            (carry0, xs, consts),
+            _mesh_specs(sharding, carry0, xs, consts)))
 
     def _test_head_dev(self, shard):
         """Cached device-resident head of the test split (the

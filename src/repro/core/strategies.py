@@ -392,11 +392,14 @@ class Strategy:
         can attribute device time to it. Scopes are metadata only.
         Training and local evaluation trace inside `fx.lowering`, so a
         mesh shard lowers the stacked CNN as the single-device run does;
-        counter `local_train.grouped_conv` records training's lowering."""
+        `fx.trained_stacked` tells `run_fused` that training ran one (it
+        sets counter `local_train.grouped_conv` from the lowering it
+        chose). `local_spec` gets the fused context in place of the
+        simulation: the program outlives the simulation that built it."""
         fl = fx.fl
         bases = self.scan_bases(fx, carry, xs)
         pids = fx.local_pids(xs["pids"])
-        spec = self.local_spec(fx.sim, None, None)
+        spec = self.local_spec(fx, None, None)
         extra = bases if spec.extra == "bases" else None
         n = jax.tree.leaves(bases)[0].shape[0]
         with jax.named_scope("local_train"), \
@@ -407,9 +410,7 @@ class Strategy:
                 bases, batch, stacked_loss_fn=spec.stacked_loss_fn,
                 lr=fl.lr, momentum=fl.momentum, extra=extra,
                 chunk=fl.fused_chunk)
-        if lowered:
-            fx.sim.telemetry.set_counter("local_train.grouped_conv",
-                                         float(lowered[0] == "grouped"))
+        fx.trained_stacked = fx.trained_stacked or bool(lowered)
         with jax.named_scope("local_eval"), fx.lowering(n):
             accs = fx.local_accs(params, pids)
         with jax.named_scope("corrupt"):
@@ -1002,11 +1003,11 @@ class CFLStrategy(Strategy):
         model, losses, accs = engine_mod.cfl_round_scan(
             carry["model"], batch, fx.eval_x[xs["pids"]],
             fx.eval_y[xs["pids"]], fl.merge_alpha,
-            loss_fn=fx.eng.loss_fn, apply_fn=fx.eng.apply_fn,
+            loss_fn=fx.loss_fn, apply_fn=fx.apply_fn,
             lr=fl.lr, momentum=fl.momentum, attack=fl.attack,
             attack_scale=fl.attack_scale, attack_flags=xs["flags"],
             attack_keys=xs["keys"], defense=fl.defense,
-            clip_tau=fl.clip_tau, codec=fx.sim.codec,
+            clip_tau=fl.clip_tau, codec=fx.codec,
             codec_keys=xs.get("ckeys"),
             fault_alive=xs.get("fault_alive"),
             fault_qok=xs.get("fault_qok"))

@@ -347,8 +347,11 @@ def test_async_counters_and_flow_trace(obs_ds):
 @pytest.mark.parametrize("engine", ["vectorized", "fused"])
 def test_dispatch_counters_per_engine(obs_ds, engine):
     """Compile counters: the fused executor counts the XLA compile of
-    its scan inside its `compile` span; the per-round engines compile
-    lazily at dispatch, outside any compile span, and count none."""
+    its scan inside its `compile` span (a fresh one: JAX's caches are
+    cleared, so no earlier simulation's program is reused); the
+    per-round engines compile lazily at dispatch, outside any compile
+    span, and count none."""
+    jax.clear_caches()
     sim = FederatedSimulation(_cfg(engine, strategy="afl"), obs_ds)
     r = sim.run()
     counters = r.extra["telemetry"]["counters"]
@@ -387,19 +390,27 @@ def test_grouped_conv_counter(obs_ds, monkeypatch, strategy, chunk, expect):
 
 
 def test_compile_counters_credit_each_run(obs_ds):
-    """Two simulations one after the other in one process: each counts
-    its own scan's compile on its own Telemetry."""
+    """Two identical simulations one after the other in one process: the
+    first builds its scan and counts the compile on its own Telemetry;
+    the second reuses that program, compiles nothing and counts the
+    reuse on its own."""
+    jax.clear_caches()
     sims = [FederatedSimulation(_cfg("fused", strategy="hfl"), obs_ds)
             for _ in range(2)]
     for sim in sims:
         sim.run()
-    for sim in sims:
-        assert sim.telemetry.counters["compile.requests"] >= 1
+    first, second = (sim.telemetry.counters for sim in sims)
+    assert first["compile.requests"] >= 1
+    assert first["fused.program_reused"] == 0.0
+    assert "compile.requests" not in second
+    assert second["fused.program_reused"] == 1.0
 
 
 def test_compile_cache_hit_credited_to_reading_run(obs_ds, tmp_path):
     """With a persistent cache directory, the second identical run reads
-    its scan from the cache and counts the hit on its own Telemetry."""
+    its scan from the cache and counts the hit on its own Telemetry
+    (JAX's in-memory caches are cleared before each run, so neither
+    reuses the program of an earlier simulation)."""
     from jax.experimental.compilation_cache import compilation_cache as cc
     keys = ("jax_compilation_cache_dir",
             "jax_persistent_cache_min_compile_time_secs",
@@ -412,6 +423,7 @@ def test_compile_cache_hit_credited_to_reading_run(obs_ds, tmp_path):
     try:
         tels = []
         for _ in range(2):
+            jax.clear_caches()
             sim = FederatedSimulation(_cfg("fused", strategy="afl"), obs_ds)
             sim.run()
             tels.append(sim.telemetry)
